@@ -343,7 +343,7 @@ def kmeans_update_segsum(w: jax.Array, d: jax.Array, spec: QuantSpec) -> Tuple[j
 
 
 def kmeans_update_stats(w: jax.Array, d: jax.Array, spec: QuantSpec,
-                        *, bn: int = 65536, interpret: Optional[bool] = None
+                        *, bn: int = 4096, interpret: Optional[bool] = None
                         ) -> Tuple[jax.Array, jax.Array]:
     """:func:`kmeans_update` through the Pallas ``kmeans_stats`` kernel.
 
@@ -363,6 +363,11 @@ def kmeans_update_stats(w: jax.Array, d: jax.Array, spec: QuantSpec,
     Same results as :func:`kmeans_update` / ``kmeans_update_segsum`` up
     to f32 accumulation order (the kernel reduces block-partials over a
     sequential grid).
+
+    ``bn`` is the kernel's block: its ``(bn, K)`` one-hot and compare
+    tiles take about 1 KiB of VMEM per element at K <= 128 and twice
+    that at K = 256, so 4096 stays inside v5e's 16 MiB scoped VMEM for
+    every dictionary size (65536 needed 66 MiB).
     """
     from repro.kernels import ops  # local: kernels.ops imports this module
 
@@ -498,12 +503,18 @@ def coarsen_dictionary(d: jax.Array, a: jax.Array, k_out: int,
 # initialization
 # ---------------------------------------------------------------------------
 
+_QUANTILE_SAMPLE = 1 << 20
+
+
 def init_dictionary(w: jax.Array, spec: QuantSpec) -> jax.Array:
     """Initialize a sorted dictionary from the weight distribution.
 
     Free/pow2 dictionaries start at the (k+0.5)/K quantiles of w (a good
     1-D k-means init that is sorted by construction); fixed dictionaries
-    are the constraint set itself.
+    are the constraint set itself. Tensors above ``_QUANTILE_SAMPLE``
+    elements take the quantiles of an evenly strided sample of about
+    that many weights: they only seed k-means, and sorting every weight
+    of a full-width layer stack would need gigabytes of scratch.
     """
     if spec.fixed_dictionary:
         base = _fixed_dictionary(spec)
@@ -514,6 +525,8 @@ def init_dictionary(w: jax.Array, spec: QuantSpec) -> jax.Array:
             return base * alpha0
         return base
     flat = w.ravel().astype(jnp.float32)
+    if flat.size > _QUANTILE_SAMPLE:
+        flat = flat[::flat.size // _QUANTILE_SAMPLE]
     qs = (jnp.arange(spec.K, dtype=jnp.float32) + 0.5) / spec.K
     d = jnp.quantile(flat, qs)
     # Quantile init can duplicate on spiky distributions; spread exact
@@ -525,5 +538,5 @@ def init_dictionary(w: jax.Array, spec: QuantSpec) -> jax.Array:
 
 def init_state(w: jax.Array, spec: QuantSpec) -> LutqState:
     d = init_dictionary(w, spec)
-    d, a = (kmeans_update_segsum if w.size >= _SEGSUM_THRESHOLD else kmeans_update)(w, d, spec)
+    d, a = _KMEANS_IMPLS[resolve_kmeans_impl(w.size)](w, d, spec)
     return LutqState(w=w, d=d, a=a)

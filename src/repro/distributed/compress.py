@@ -74,12 +74,7 @@ def ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     x: the *local* shard, chunked along dim 0 into `n` pieces. Total
     bytes on the wire per device: 2 * (n-1)/n * |x| — the textbook ring.
     """
-    # jax.lax.axis_size is missing on older jax; psum of a literal 1
-    # resolves to a concrete int under shard_map there.
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -183,8 +178,6 @@ def _ring_transform(grads, ef, mesh, pspecs=None):
     participate (dp_axis_size here would shrink gradients by the pod
     factor on a multi-pod mesh).
     """
-    from jax.experimental.shard_map import shard_map
-
     n = int(mesh.shape["data"])
     g_leaves, treedef, idx = _flatten_grads(grads)
     e_leaves, _, _ = _flatten_grads(ef)
@@ -214,9 +207,9 @@ def _ring_transform(grads, ef, mesh, pspecs=None):
             return tuple(outs_g), tuple(outs_e)
 
         specs = tuple(spec_leaves[i] for i in ring_idx)
-        new_g, new_e = shard_map(
+        new_g, new_e = jax.shard_map(
             local, mesh=mesh, in_specs=(specs, specs),
-            out_specs=(specs, specs), check_rep=False)(
+            out_specs=(specs, specs), check_vma=False)(
                 tuple(g_leaves[i] for i in ring_idx),
                 tuple(e_leaves[i] for i in ring_idx))
         for j, i in enumerate(ring_idx):

@@ -3,8 +3,7 @@
 The compiled-mode perf story of this repo rests on tile choice: the
 fused/packed kernels stream the assignment matrix HBM->VMEM in
 (bk x bn) blocks and decode in front of the MXU, so the right bm/bn/bk
-(and the dictionary-placement *strategy* — MXU-friendly one-hot matmul
-vs the Mosaic gather ``d[a]``) is a per-shape, per-platform property.
+is a per-shape, per-platform property.
 This module searches a pruned candidate grid per
 
     (kernel, M, N, Kin, K, dtype, backend, platform)
@@ -19,10 +18,10 @@ Candidate grids
 ---------------
 * **Real TPU** (``platform == "tpu"``): MXU-aligned tiles — bm in
   sublane multiples, bn/bk in lane multiples — pruned by a VMEM budget
-  (x block + assignment block + f32 accumulator + the one-hot decode
-  temporary must fit comfortably in ~16 MB).
+  (x block + assignment block + f32 accumulator + the decoded weight
+  tile must fit comfortably in ~16 MB).
 * **Interpret mode** (CPU/GPU emulation, this container): a tiny grid
-  that varies only bm/bn and the decode strategy while pinning the k
+  that varies only bm/bn while pinning the k
   extent to the whole (padded) reduction axis. Splitting k changes the
   f32 accumulation grouping; keeping it whole matches the default-tile
   reduction order exactly, so every interpret-mode candidate is
@@ -33,7 +32,9 @@ Candidate grids
 Candidates are generated in deterministic sorted order and the timing
 loop takes a strict improvement to switch winners, so repeated searches
 on the same machine are stable; ``tune(measure=...)`` injects the
-timing function for deterministic tests.
+timing function for deterministic tests. A search in which every
+candidate fails raises: an untimed default is never recorded as a
+winner.
 """
 from __future__ import annotations
 
@@ -50,9 +51,6 @@ import jax.numpy as jnp
 #: kernel component of a cache key per backend
 KERNEL_OF_BACKEND = {"fused": "matmul", "packed4": "gemv_packed",
                      "pow2": "shift"}
-
-#: decode strategies the kernels implement (dictionary placement)
-STRATEGIES = ("onehot", "gather")
 
 #: paged-attention decode dispatch choices: the Pallas block-table
 #: kernel vs the gather+decode_attention oracle. Both are bit-identical
@@ -93,14 +91,14 @@ class TileConfig:
 
     ``bm``/``bn``/``bk`` are tile *limits* (``lutq_dot`` clamps them to
     the padded operand dims, so ``bk >= Kin`` means "one k step").
-    ``strategy`` picks the dictionary placement: ``"onehot"`` decodes
-    via an (idx == iota) @ d matmul, ``"gather"`` via ``jnp.take``.
+    ``strategy`` is set by paged-attention entries only: the dispatch
+    (``"kernel"`` | ``"gather"``) that won; matmul tiles leave it empty.
     """
 
     bm: int
     bn: int
     bk: int
-    strategy: str = "onehot"
+    strategy: str = ""
 
     def to_json_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -108,7 +106,7 @@ class TileConfig:
     @classmethod
     def from_json_dict(cls, d: Dict) -> "TileConfig":
         return cls(bm=int(d["bm"]), bn=int(d["bn"]), bk=int(d["bk"]),
-                   strategy=str(d.get("strategy", "onehot")))
+                   strategy=str(d.get("strategy", "")))
 
 
 def make_key(kernel: str, M: int, N: int, Kin: int, K: int, dtype,
@@ -190,15 +188,15 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _vmem_bytes(bm: int, bn: int, bk: int, K: int, strategy: str,
-                packed: bool) -> int:
-    """Rough per-step VMEM footprint of one fused-kernel grid cell."""
+def _vmem_bytes(bm: int, bn: int, bk: int, packed: bool) -> int:
+    """Rough per-step VMEM footprint of one fused-kernel grid cell:
+    double-buffered x and assignment blocks, the f32 accumulator, and
+    the int32 index + decoded weight tiles."""
     a_bytes = (bk // 2) * bn if packed else bk * bn
     x_bytes = bm * bk * 4
     acc_bytes = bm * bn * 4
-    w_bytes = bk * bn * 4  # decoded tile
-    onehot = bk * bn * K * 4 if strategy == "onehot" else 0
-    return x_bytes + a_bytes + acc_bytes + w_bytes + onehot + K * 4
+    tile_bytes = 2 * bk * bn * 4
+    return 2 * (x_bytes + a_bytes) + acc_bytes + tile_bytes
 
 
 def candidates(kernel: str, M: int, N: int, Kin: int, K: int, *,
@@ -207,7 +205,7 @@ def candidates(kernel: str, M: int, N: int, Kin: int, K: int, *,
 
     Interpret mode pins ``bk`` to the full reduction extent (single k
     step == default reduction grouping == bit-identical outputs) and
-    varies bm/bn/strategy only. Real TPU searches MXU-aligned bk too,
+    varies bm/bn only. Real TPU searches MXU-aligned bk too,
     pruned by the VMEM budget.
     """
     interpret = default_interpret() if interpret is None else interpret
@@ -223,13 +221,12 @@ def candidates(kernel: str, M: int, N: int, Kin: int, K: int, *,
         bk = max(512, _round_up(Kin, 2))
         bms = sorted({b for b in (8, 32, 256) if b <= _round_up(M, 8)} | {256})
         if packed:
-            bms = [256]  # the gemv keeps x whole; bm is unused
+            bms = [256]  # holds a decode batch whole; prefill tiles by it
         bns = sorted({b for b in (32, 128, 256) if b <= _round_up(N, 8)}
                      | {256})
         for bm in bms:
             for bn in bns:
-                for strat in STRATEGIES:
-                    out.append(TileConfig(bm=bm, bn=bn, bk=bk, strategy=strat))
+                out.append(TileConfig(bm=bm, bn=bn, bk=bk))
     else:
         bms = ([256] if packed else
                [b for b in (8, 64, 128, 256, 512)
@@ -240,14 +237,8 @@ def candidates(kernel: str, M: int, N: int, Kin: int, K: int, *,
         for bm in bms:
             for bn in bns:
                 for bk in bks:
-                    if packed and bk % 2:
-                        continue
-                    for strat in STRATEGIES:
-                        if _vmem_bytes(bm, bn, bk, K, strat,
-                                       packed) > _VMEM_BUDGET:
-                            continue
-                        out.append(TileConfig(bm=bm, bn=bn, bk=bk,
-                                              strategy=strat))
+                    if _vmem_bytes(bm, bn, bk, packed) <= _VMEM_BUDGET:
+                        out.append(TileConfig(bm=bm, bn=bn, bk=bk))
     # deterministic order -> stable winner under timing ties
     return sorted(out, key=lambda t: (t.bm, t.bn, t.bk, t.strategy))
 
@@ -300,7 +291,8 @@ def tune(kernel: str, *, M: int, N: int, Kin: int, K: int,
 
     ``measure`` (tests): maps a TileConfig to a time, replacing the real
     benchmark loop. The winner is the first strict minimum in candidate
-    order, so equal-time candidates resolve deterministically.
+    order, so equal-time candidates resolve deterministically. Raises
+    ``RuntimeError`` when every candidate fails (infinite time).
     """
     import functools
 
@@ -322,7 +314,7 @@ def tune(kernel: str, *, M: int, N: int, Kin: int, K: int,
         def measure(tile: TileConfig) -> float:
             fn = jax.jit(functools.partial(
                 ops.lutq_dot, backend=backend, bm=tile.bm, bn=tile.bn,
-                bk=tile.bk, strategy=tile.strategy, interpret=interpret))
+                bk=tile.bk, interpret=interpret))
             return measure_call(fn, x, state, reps=reps, warmup=warmup)
 
     best: Optional[TileConfig] = None
@@ -330,11 +322,12 @@ def tune(kernel: str, *, M: int, N: int, Kin: int, K: int,
     timings: Dict[str, float] = {}
     for tile in candidates(kernel, M, N, Kin, K, interpret=interpret):
         us = measure(tile)
-        timings[f"{tile.bm}x{tile.bn}x{tile.bk}/{tile.strategy}"] = us
+        name = f"{tile.bm}x{tile.bn}x{tile.bk}"
+        timings[name + (f"/{tile.strategy}" if tile.strategy else "")] = us
         if us < best_us:
             best, best_us = tile, us
-    if best is None:  # every candidate infeasible: keep defaults
-        best = TileConfig(bm=256, bn=256, bk=512)
+    if best is None:
+        raise RuntimeError(f"autotune {key}: every candidate failed")
     if cache is not None:
         cache.put(key, best)
     return key, best, timings
@@ -406,5 +399,5 @@ def tune_tree(params, *, batch_m: int = 8, dtype=jnp.float32,
             emit(f"[autotune] {rec['kernel']:12s} M={rec['M']:<5d} "
                  f"N={rec['N']:<6d} Kin={rec['Kin']:<6d} K={rec['K']:<4d} -> "
                  f"bm={tile.bm} bn={tile.bn} bk={tile.bk} "
-                 f"{tile.strategy} ({len(rec['paths'])} leaves)")
+                 f"({len(rec['paths'])} leaves)")
     return cache

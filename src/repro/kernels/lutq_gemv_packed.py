@@ -3,42 +3,37 @@
 Decode at batch B is HBM-bandwidth-bound: wall time ~ weight bytes / HBM
 bw. LUT-Q with K=16 stores 4 bits/weight; this kernel keeps the
 assignment matrix PACKED in HBM (two indices per byte), unpacks nibbles
-in VMEM, decodes against the dictionary and runs the (small-M) matmul —
-weight traffic is Kin*N/2 bytes vs 2*Kin*N for bf16: a 4x reduction of
-the dominant roofline term for decode.
+in VMEM, decodes against the SMEM dictionary (compare-and-select, see
+``lutq_matmul.select_decode``) and runs the (small-M) matmul — weight
+traffic is Kin*N/2 bytes vs 2*Kin*N for bf16: a 4x reduction of the
+dominant roofline term for decode.
 
-Grid: (N/bn, Kin/bk) with k innermost; x fits VMEM whole (B is small at
-decode time).
+Grid: (B/bm, N/bn, Kin/bk) with k innermost. At decode time B is small
+and one bm block holds it whole; a long prefill tiles B, so x and the
+f32 output stay inside scoped VMEM.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lutq_matmul import SMEM_WHOLE, select_decode, smem_row
 
-def _kernel(x_ref, p_ref, d_ref, o_ref, *, n_dict: int, decode_onehot: bool):
-    k = pl.program_id(1)
 
-    @pl.when(k == 0)
+def _kernel(x_ref, p_ref, d_ref, o_ref):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    packed = p_ref[...]                     # (bk/2, bn) uint8/int8
-    lo = (packed & 0xF).astype(jnp.int32)   # even rows
-    hi = ((packed >> 4) & 0xF).astype(jnp.int32)
+    # widen before the nibble ops: Mosaic has no 8-bit vector shifts
+    packed = p_ref[...].astype(jnp.int32)   # (bk/2, bn)
+    lo = packed & 0xF                       # even rows
+    hi = (packed >> 4) & 0xF
     bk2, bn = packed.shape
     idx = jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)
-    d = d_ref[...]
-    if decode_onehot:
-        onehot = (idx.reshape(-1, 1) ==
-                  jnp.arange(n_dict, dtype=jnp.int32)[None, :]).astype(d.dtype)
-        w = (onehot @ d.reshape(n_dict, 1)).reshape(bk2 * 2, bn)
-    else:
-        w = jnp.take(d, idx, axis=0)        # Mosaic-friendly gather
-    x = x_ref[...]                          # (B, bk)
+    w = select_decode(idx, d_ref)
+    x = x_ref[...]                          # (bm, bk)
     o_ref[...] += jax.lax.dot_general(
         x, w.astype(x.dtype),
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -50,33 +45,28 @@ def lutq_gemv_packed(
     packed: jax.Array,   # (Kin/2, N) uint8 — two 4-bit indices per byte
     d: jax.Array,        # (16,) float32
     *,
+    bm: int = 256,
     bn: int = 256,
     bk: int = 512,
-    decode_onehot: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
     B, Kin = x.shape
     Kin2, N = packed.shape
     assert Kin == Kin2 * 2
-    n_dict = d.shape[0]
-    # 4-bit packing caps the *live* dictionary at 16 entries; compiled
-    # mode may lane-pad d to a 128 multiple (nibbles never index the pad)
-    assert n_dict <= 16 or n_dict % 128 == 0, \
-        "packed layout is 4-bit (K <= 16, or 128-lane-padded)"
-    bn, bk = min(bn, N), min(bk, Kin)
-    assert N % bn == 0 and Kin % bk == 0 and bk % 2 == 0
+    assert d.shape[0] <= 16, "packed layout is 4-bit (K <= 16)"
+    bm, bn, bk = min(bm, B), min(bn, N), min(bk, Kin)
+    assert B % bm == 0 and N % bn == 0 and Kin % bk == 0 and bk % 2 == 0
 
-    grid = (N // bn, Kin // bk)
+    grid = (B // bm, N // bn, Kin // bk)
     return pl.pallas_call(
-        functools.partial(_kernel, n_dict=n_dict,
-                          decode_onehot=decode_onehot),
+        _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((B, bk), lambda j, k: (0, k)),
-            pl.BlockSpec((bk // 2, bn), lambda j, k: (k, j)),
-            pl.BlockSpec((n_dict,), lambda j, k: (0,)),
+            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
+            SMEM_WHOLE,
         ],
-        out_specs=pl.BlockSpec((B, bn), lambda j, k: (0, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
-    )(x, packed, d)
+    )(x, packed, smem_row(d))

@@ -18,7 +18,7 @@ Backends
              training — the numerics oracle for everything else.
 ``fused``    :mod:`repro.kernels.lutq_matmul` — int8 assignments stream
              HBM->VMEM at 1 byte/weight and decode against the
-             VMEM-resident dictionary in front of the MXU.
+             SMEM-resident dictionary in front of the MXU.
 ``packed4``  :mod:`repro.kernels.lutq_gemv_packed` — 4-bit pairs stay
              packed in HBM (0.5 byte/weight), unpacked in VMEM.
 ``pow2``     :mod:`repro.kernels.lutq_shift` — pow2 dictionaries stored
@@ -61,7 +61,7 @@ from repro.kernels.ref import (  # noqa: F401  (re-export for callers)
 BACKENDS = ("auto", "decode", "fused", "packed4", "pow2")
 
 #: Default tiles when the tuning cache has no entry for a shape.
-DEFAULT_TILE = TileConfig(bm=256, bn=256, bk=512, strategy="onehot")
+DEFAULT_TILE = TileConfig(bm=256, bn=256, bk=512)
 
 # process-level tuning cache: ``lutq_dot`` consults it at trace time,
 # ``--autotune cache|search`` fills it, ``serve_view`` / checkpoints
@@ -85,34 +85,23 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "strategy", "interpret"))
-def lutq_matmul(x, a, d, *, bm=256, bn=256, bk=512, strategy="onehot",
-                interpret=None):
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def lutq_matmul(x, a, d, *, bm=256, bn=256, bk=512, interpret=None):
     interpret = _default_interpret() if interpret is None else interpret
-    return _lutq_matmul(x, a, d, bm=bm, bn=bn, bk=bk,
-                        decode_onehot=(strategy == "onehot"),
+    return _lutq_matmul(x, a, d, bm=bm, bn=bn, bk=bk, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def lutq_gemv_packed(x, packed, d, *, bm=256, bn=256, bk=512, interpret=None):
+    interpret = _default_interpret() if interpret is None else interpret
+    return _gemv_packed(x, packed, d, bm=bm, bn=bn, bk=bk,
                         interpret=interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bn", "bk", "strategy", "interpret"))
-def lutq_gemv_packed(x, packed, d, *, bn=256, bk=512, strategy="onehot",
-                     interpret=None):
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def lutq_shift(xq, a, wsh, *, bm=256, bn=256, bk=512, interpret=None):
     interpret = _default_interpret() if interpret is None else interpret
-    return _gemv_packed(x, packed, d, bn=bn, bk=bk,
-                        decode_onehot=(strategy == "onehot"),
-                        interpret=interpret)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "strategy", "interpret"))
-def lutq_shift(xq, a, wsh, *, bm=256, bn=256, bk=512, strategy="onehot",
-               interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _lutq_shift(xq, a, wsh, bm=bm, bn=bn, bk=bk,
-                       decode_onehot=(strategy == "onehot"),
-                       interpret=interpret)
+    return _lutq_shift(xq, a, wsh, bm=bm, bn=bn, bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -248,7 +237,7 @@ def _pow2_act_quant(x2, act, axis_name=None):
 
 
 def _pow2_dot_acc(x2, code, a, act, *, transpose_rhs=False, axis_name=None,
-                  use_kernel=True, bm=None, bn=None, bk=None, strategy=None,
+                  use_kernel=True, bm=None, bn=None, bk=None,
                   interpret=None):
     """(int32 accumulator (M, N), f32 epilogue scale) of the pow2 path.
 
@@ -274,7 +263,6 @@ def _pow2_dot_acc(x2, code, a, act, *, transpose_rhs=False, axis_name=None,
     bm = tile.bm if bm is None else bm
     bn = tile.bn if bn is None else bn
     bk = tile.bk if bk is None else bk
-    strategy = tile.strategy if strategy is None else strategy
     base_m = 1 if interpret else 8
     base_l = 1 if interpret else 128
     tm, Mp = _tile(M, bm, base_m)
@@ -284,11 +272,7 @@ def _pow2_dot_acc(x2, code, a, act, *, transpose_rhs=False, axis_name=None,
         xq = jnp.pad(xq, ((0, Mp - M), (0, Kp - Kin)))
     if Kp != Kin or Np != N:
         a = jnp.pad(a, ((0, Kp - Kin), (0, Np - N)))
-    if not interpret and K % base_l:
-        # padded dictionary entries are never indexed (assignments < K)
-        wsh = jnp.pad(wsh, (0, _round_up(K, base_l) - K))
-    acc = lutq_shift(xq, a, wsh, bm=tm, bn=tn, bk=tk, strategy=strategy,
-                     interpret=interpret)
+    acc = lutq_shift(xq, a, wsh, bm=tm, bn=tn, bk=tk, interpret=interpret)
     return acc[:M, :N], scale
 
 
@@ -302,7 +286,6 @@ def lutq_dot(
     bm: int = None,
     bn: int = None,
     bk: int = None,
-    strategy: str = None,
     interpret: bool = None,
 ) -> jax.Array:
     """``x @ d[A]`` (or ``x @ d[A].T``) through the resolved backend.
@@ -313,10 +296,10 @@ def lutq_dot(
     (those fall back to the dense decode path, which also carries the
     training STE). Returns (..., N) in ``out_dtype`` (default x.dtype).
 
-    Tile sizes and decode strategy default to the process
-    :class:`TuningCache` entry for this (kernel, shape, dtype, platform)
-    key — :data:`DEFAULT_TILE` when untuned. Explicit ``bm/bn/bk/
-    strategy`` arguments override the cache field-by-field. Callers that
+    Tile sizes default to the process :class:`TuningCache` entry for
+    this (kernel, shape, dtype, platform) key — :data:`DEFAULT_TILE`
+    when untuned. Explicit ``bm/bn/bk`` arguments override the cache
+    field-by-field. Callers that
     jit around ``lutq_dot`` must salt their jit/lru keys with
     :func:`tuning_fingerprint` or a tile tuned after the first trace
     would be silently ignored.
@@ -326,6 +309,7 @@ def lutq_dot(
     (padded x rows/K-columns are zero, padded assignment entries index
     dictionary slot 0 against zero activations, padded dictionary lanes
     are never indexed), and the pad is sliced off the f32 kernel output.
+    The dictionary stays unpadded: the kernels read it as SMEM scalars.
     """
     be = resolve_backend(state, backend, transpose_rhs=transpose_rhs)
     out_dtype = out_dtype or x.dtype
@@ -367,7 +351,7 @@ def lutq_dot(
     if be == "pow2":
         acc, scale = _pow2_dot_acc(x2, d, state.a, state.act,
                                    transpose_rhs=transpose_rhs,
-                                   bm=bm, bn=bn, bk=bk, strategy=strategy,
+                                   bm=bm, bn=bn, bk=bk,
                                    interpret=interpret)
         y = acc.astype(jnp.float32) * scale
         N = y.shape[-1]
@@ -379,7 +363,6 @@ def lutq_dot(
         bm = tile.bm if bm is None else bm
         bn = tile.bn if bn is None else bn
         bk = tile.bk if bk is None else bk
-        strategy = tile.strategy if strategy is None else strategy
         tm, Mp = _tile(M, bm, base_m)
         tn, Np = _tile(N, bn, base_l)
         tk, Kp = _tile(Kin, bk, base_l)
@@ -387,32 +370,24 @@ def lutq_dot(
             x2 = jnp.pad(x2, ((0, Mp - M), (0, Kp - Kin)))
         if Kp != Kin or Np != N:
             a = jnp.pad(a, ((0, Kp - Kin), (0, Np - N)))
-        if not interpret and K % base_l:
-            # compiled 1-D VMEM blocks want lane-multiple extents; the
-            # padded entries are never indexed (assignments < K), so
-            # decode stays exact
-            d = jnp.pad(d, (0, _round_up(K, base_l) - K))
-        y = lutq_matmul(x2, a, d, bm=tm, bn=tn, bk=tk, strategy=strategy,
-                        interpret=interpret)
+        y = lutq_matmul(x2, a, d, bm=tm, bn=tn, bk=tk, interpret=interpret)
         y = y[:M, :N]
     else:  # packed4: x (M, Kin) @ unpack(packed (Kin/2, N))
         p = state.a
         assert p.shape[0] * 2 == Kin, (p.shape, x.shape)
         N = p.shape[1]
         tile = _tuned_tile(be, M, N, Kin, K, x.dtype, interpret)
+        bm = tile.bm if bm is None else bm
         bn = tile.bn if bn is None else bn
         bk = tile.bk if bk is None else bk
-        strategy = tile.strategy if strategy is None else strategy
-        Mp = _round_up(M, base_m)  # sublane-pad M for the compiled MXU
+        tm, Mp = _tile(M, bm, base_m)
         tn, Np = _tile(N, bn, base_l)
         tk, Kp = _tile(Kin, bk, 2 if interpret else 2 * base_l)
         if Mp != M or Kp != Kin:
             x2 = jnp.pad(x2, ((0, Mp - M), (0, Kp - Kin)))
         if Kp != Kin or Np != N:
             p = jnp.pad(p, ((0, (Kp - Kin) // 2), (0, Np - N)))
-        if not interpret and K % base_l:
-            d = jnp.pad(d, (0, _round_up(K, base_l) - K))
-        y = lutq_gemv_packed(x2, p, d, bn=tn, bk=tk, strategy=strategy,
+        y = lutq_gemv_packed(x2, p, d, bm=tm, bn=tn, bk=tk,
                              interpret=interpret)
         y = y[:M, :N]
     return y.reshape(*lead, N).astype(out_dtype)
@@ -470,7 +445,6 @@ def lutq_dot_spmd(
     across the sharded matmul axes — LUT-Q's tiny-d / big-A split is
     exactly what makes this cheap.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     nstack = state.a.ndim - 2
@@ -518,16 +492,20 @@ def lutq_dot_spmd(
             acc = jax.lax.psum(acc, k_entry)
             return (acc.astype(jnp.float32) * scale).astype(
                 out_dtype or x_l.dtype)
+        # K-sharded partials stay f32 through the psum and round once,
+        # like the single-device kernel's f32 accumulator
+        part_dtype = (out_dtype or x_l.dtype) if k_entry is None \
+            else jnp.float32
         if nstack:
             y = jax.vmap(lambda xe, de, ae, ce: lutq_dot(
                 xe, LutqState(w=None, d=de, a=ae, act=ce), backend=backend,
-                out_dtype=out_dtype))(x_l, d_l, a_l, act_l)
+                out_dtype=part_dtype))(x_l, d_l, a_l, act_l)
         else:
             y = lutq_dot(x_l, LutqState(w=None, d=d_l, a=a_l, act=act_l),
                          backend=backend,
-                         transpose_rhs=transpose_rhs, out_dtype=out_dtype)
+                         transpose_rhs=transpose_rhs, out_dtype=part_dtype)
         if k_entry is not None:
-            y = jax.lax.psum(y, k_entry)
+            y = jax.lax.psum(y, k_entry).astype(out_dtype or x_l.dtype)
         return y
 
     operands = [x, state.d, state.a]
@@ -537,9 +515,8 @@ def lutq_dot_spmd(
         # sharded matmul axes, like the dictionary
         operands.append(state.act)
         in_specs.append(P(stack_entry, None) if nstack else P(None))
-    return shard_map(local, mesh=mesh,
-                     in_specs=tuple(in_specs),
-                     out_specs=out_spec, check_rep=False)(*operands)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_spec, check_vma=False)(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -607,24 +584,34 @@ jax.tree_util.register_pytree_node(
 def annotate_spmd(params, axes, mesh):
     """Wrap serve-form LutqState leaves with their serve PartitionSpecs.
 
-    Call *inside* a meshed jit on the params tracers. Only leaves whose
-    assignment spec actually names a mesh axis are wrapped — replicated
-    leaves (and train-form / non-LutqState leaves) pass through, so the
-    decode fallback and unsharded paths are byte-identical to before.
+    Call *inside* a meshed jit on the params tracers. Leaves whose
+    assignment spec names a mesh axis are wrapped. Replicated leaves are
+    wrapped too when the kernels compile for the TPU, where a Pallas
+    kernel under a multi-device jit must run inside a shard_map; in
+    interpret mode the kernels are plain XLA that the partitioner
+    handles, so those leaves pass through and keep the layouts
+    propagation gives them. Train-form and non-LutqState leaves pass
+    through.
     """
     if mesh is None:
         return params
     from repro.distributed.sharding import serve_pspecs
 
     pspecs = serve_pspecs(axes, mesh, params)
+    interpret = _default_interpret()
 
     def wrap(leaf, spec):
         if not isinstance(leaf, LutqState) or leaf.w is not None:
             return leaf
         a_spec = getattr(spec, "a", None)
-        if a_spec is None or not any(e is not None for e in tuple(a_spec)):
+        live = a_spec is not None and any(e is not None for e in a_spec)
+        if not live and interpret:
             return leaf
-        return SpmdLutqState(leaf, mesh, a_spec)
+        # full rank, so lutq_dot_sharded's right-alignment after scan
+        # slicing maps each entry to its own dim: P(None, "model") on a
+        # (L, K, N) stack shards K, not N
+        return SpmdLutqState(leaf, mesh, jax.sharding.PartitionSpec(
+            *_spec_parts(a_spec, leaf.a.ndim)))
 
     return jax.tree_util.tree_map(
         wrap, params, pspecs,
@@ -644,8 +631,9 @@ def lutq_dot_sharded(
     scan-over-layers slices leading stack axes off the *arrays* but not
     off the recorded spec, so the spec's trailing entries are
     right-aligned to the runtime assignment rank. Leaves that resolve to
-    ``decode``, or whose live spec entries are all None, take the plain
-    :func:`lutq_dot` path (GSPMD shards dense decode fine on its own).
+    ``decode`` take the plain :func:`lutq_dot` path (GSPMD shards dense
+    decode fine on its own); a kernel on a replicated leaf still runs
+    under shard_map, computing the whole product on every device.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -658,9 +646,7 @@ def lutq_dot_sharded(
     nstack = state.a.ndim - 2
     be = resolve_backend(state, backend, transpose_rhs=transpose_rhs,
                          sliced=True)
-    live = any(e is not None for e in parts)
-    if (be == "decode" or not live or nstack not in (0, 1)
-            or (nstack and transpose_rhs)):
+    if be == "decode" or nstack not in (0, 1) or (nstack and transpose_rhs):
         return lutq_dot(x, state, backend=backend,
                         transpose_rhs=transpose_rhs, out_dtype=out_dtype)
     return lutq_dot_spmd(x, state, leaf.mesh, a_spec=P(*parts),
@@ -711,7 +697,6 @@ def _paged_attention_sharded(q, k_pool, v_pool, block, cache_len, *,
     construction. Falls back to the gather oracle (which GSPMD
     partitions on its own) when an axis does not divide.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.paged_attn import paged_attention_tpu
@@ -741,9 +726,9 @@ def _paged_attention_sharded(q, k_pool, v_pool, block, cache_len, *,
     if quant:
         in_specs += [P(None, None, tp), P(None, None, tp)]
         operands += [k_scale, v_scale]
-    return shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(dp, None, tp, None),
-                     check_rep=False)(*operands)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(dp, None, tp, None),
+                         check_vma=False)(*operands)
 
 
 def paged_attention(q, k_pool, v_pool, block, cache_len, *, window=None,
@@ -767,7 +752,7 @@ def paged_attention(q, k_pool, v_pool, block, cache_len, *, window=None,
     KV-head-sharded serving keeps shard-local pages.
     ``vmem_budget_bytes`` caps the kernel's per-row VMEM scratch (see
     ``paged_attn.vmem_plan``): rows too long for the single-pass scratch
-    run the bit-identical multi-pass split instead of failing.
+    run the two-pass kernel instead of failing.
     """
     interpret = _default_interpret() if interpret is None else interpret
     if backend not in PAGED_BACKENDS:

@@ -214,8 +214,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
                 "alias_bytes": int(ma.alias_size_in_bytes),
             }
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # older jax: list per device
-                ca = ca[0] if ca else {}
             rec["cost"] = {
                 "flops": float(ca.get("flops", -1)),
                 "bytes_accessed": float(ca.get("bytes accessed", -1)),

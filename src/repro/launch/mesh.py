@@ -13,30 +13,30 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    # axis_types / AxisType only exist on newer jax; Auto is the default
-    # behavior there anyway, so older versions just omit the kwarg.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-_make_mesh = compat_make_mesh
+def _auto_mesh(shape, axes, **kw):
+    # Auto axes: shardings stay annotations that GSPMD propagates (the
+    # default Explicit axes would put them into every array's type)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over whatever devices exist (tests / CPU runs)."""
+    """A (data, model) mesh over this host's first data*model devices.
+
+    Raises when the host has fewer: a mesh silently shrunk to the
+    devices present would run, and report, a different layout."""
     n = len(jax.devices())
-    data = min(data, n)
-    model = max(1, min(model, n // data))
-    return _make_mesh((data, model), ("data", "model"))
+    if data * model > n:
+        raise ValueError(f"a {data}x{model} mesh needs {data * model} "
+                         f"devices; this host has {n}")
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_arg(spec: str):

@@ -41,6 +41,7 @@ from repro.core.policy import (backend_manifest, effective_bits,
 from repro.core.rules import get_policy
 from repro.core.spec import QuantSpec
 from repro.kernels.ops import BACKENDS
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.partition import device_nbytes
 from repro.models import api
 from repro.models.reduce import reduced
@@ -157,7 +158,7 @@ def run_engine(params, cfg, *, capacity: int, n_requests: int,
                kv_pages=None, page_size: int = 64,
                prefix_cache: bool = True, requests=None,
                speculative: int = 0, draft_bits: int = 3,
-               draft_params=None):
+               draft_params=None, probe=None):
     """Serve a ragged queue through the continuous-batching engine and
     return its stats dict (shared by the CLI and the example, so both
     report identical fields).
@@ -165,7 +166,9 @@ def run_engine(params, cfg, *, capacity: int, n_requests: int,
     ``kv_pages`` switches supported families onto the paged KV cache
     (block-table pages + prefix sharing; see docs/serving.md).
     ``requests`` overrides the synthetic workload with an explicit list
-    of ``Engine.submit`` kwargs dicts."""
+    of ``Engine.submit`` kwargs dicts. ``probe``, when given, is called
+    with the engine once every request is submitted, before it runs: a
+    check may step it, and may keep it to read ``results`` afterwards."""
     from repro.runtime.engine import synthetic_requests
 
     src_len = prompt_len if cfg.family == "encdec" else 0
@@ -183,6 +186,8 @@ def run_engine(params, cfg, *, capacity: int, n_requests: int,
         req = dict(req)
         req.pop("arrival_s", None)
         eng.submit(**req)
+    if probe is not None:
+        probe(eng)
     eng.run()
     return eng.stats()
 
@@ -304,6 +309,7 @@ def main(argv=None):
                          "written by --autotune search")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -348,17 +354,25 @@ def main(argv=None):
         qparams = merge_trainable(state["trainable"], state["static"])
         axes = api.init_axes(cfg)
         fp_bytes = footprint_bytes(state["trainable"])
+        del state
         print(f"[serve] restored train checkpoint step {step} from "
               f"{args.ckpt_dir}"
               + (" (policy from manifest)" if ckpt_policy is not None else ""))
     else:
         params, axes = api.init(jax.random.PRNGKey(args.seed), cfg)
         fp_bytes = footprint_bytes(params)
-        qparams = api.quantize(params, cfg, axes)
+        # one program, not op-by-op dispatch over every layer; donated,
+        # so the fp masters alias the input instead of a second copy
+        qparams = jax.jit(lambda p: api.quantize(p, cfg, axes),
+                          donate_argnums=0)(params)
+        del params
     policy = api.resolved_policy(cfg)
     pack = args.pack4 or args.kernel_backend == "packed4"
     sparams = serve_view(qparams, pack4=pack, policy=policy,
                          mesh=mesh, axes=axes)
+    # the fp masters are dead weight from here on: let them go before
+    # serving (at full width they are most of the device's memory)
+    del qparams
     manifest = backend_manifest(sparams, policy,
                                 override=args.kernel_backend)
 
@@ -403,8 +417,7 @@ def main(argv=None):
             tile = tc.get(key) or ops.DEFAULT_TILE
             for path in rec["paths"]:
                 print(f"[serve]   tile {path}: {rec['backend']} "
-                      f"bm={tile.bm} bn={tile.bn} bk={tile.bk} "
-                      f"{tile.strategy}"
+                      f"bm={tile.bm} bn={tile.bn} bk={tile.bk}"
                       + ("" if tc.get(key) else " (default, untuned)"))
     q_bytes = footprint_bytes(sparams)
     print(f"[serve] {cfg.name}: weights fp32 {fp_bytes/2**20:.2f} MiB -> "

@@ -33,6 +33,7 @@ from repro.core.spec import QuantSpec
 from repro.data.synthetic import MarkovLM
 from repro.distributed.compress import (GRAD_COMPRESS_MODES,
                                         dp_grad_transform, trainable_pspecs)
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh, parse_mesh_arg
 from repro.launch import partition
 from repro.models import api
@@ -180,6 +181,7 @@ def main(argv=None):
                     help="decode batch M the --autotune search tunes for "
                          "(match the serve engine's --max-batch)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     mesh = None
     if args.mesh:

@@ -172,7 +172,8 @@ def _paged_step_fn_cached(cfg: ModelConfig, greedy: bool, mesh,
     def step(params, tok, cache, keys, temp):
         if mesh is not None:
             params = ops.annotate_spmd(params, axes, mesh)
-        logits, cache = api.paged_decode_step(params, cfg, tok, cache)
+        logits, cache = api.paged_decode_step(params, cfg, tok, cache,
+                                              mesh=mesh)
         tok, keys = _sample(logits, keys, temp, greedy)
         return tok, cache, keys
 
@@ -406,6 +407,7 @@ class Engine:
         self.tok = jnp.zeros((self.capacity, 1), jnp.int32)
         self.keys = jnp.stack([jax.random.fold_in(self._base_rng, i)
                                for i in range(self.capacity)])
+        self._pool_sharding = None
         if mesh is not None:
             from repro.launch.partition import (paged_serve_shardings,
                                                 serve_shardings)
@@ -422,6 +424,8 @@ class Engine:
             self.cache = jax.device_put(self.cache, sh["cache"])
             self.tok = jax.device_put(self.tok, sh["token"])
             self.keys = jax.device_put(self.keys, sh["keys"])
+            if self.paged:
+                self._pool_sharding = sh["cache"]["pool"]
         self.slots: List[Optional[Request]] = [None] * self.capacity
         self.queue: deque = deque()
         self._pending: List[jax.Array] = []  # un-synced decode tokens
@@ -714,7 +718,7 @@ class Engine:
         C = paged_kv.next_pow2(max(tail_tot, self.buckets[0]))
         toks = np.zeros((1, C), np.int32)
         toks[0, :tail_tot] = np.concatenate(tails)
-        logits, pool = paged_packed_fn(cfg, self.wws)(
+        logits, pool = paged_packed_fn(cfg, self.wws, self.mesh)(
             self.params, jnp.asarray(toks), self.cache["pool"],
             jnp.asarray(blocks), jnp.asarray(bases), jnp.asarray(hists),
             jnp.asarray(lens))
@@ -722,7 +726,7 @@ class Engine:
         self.n_packed_groups += 1
         self.n_packed_reqs += len(admitted)
         self.cache = dict(self.cache)
-        self.cache["pool"] = pool
+        self.cache["pool"] = self._placed_pool(pool)
         for s, (slot, req, row, hit) in enumerate(admitted):
             L = len(req.tokens)
             self.cache["block"] = self.cache["block"].at[slot].set(
@@ -762,7 +766,7 @@ class Engine:
         req = st["req"]
         toks = np.zeros((1, width), np.int32)
         toks[0, :n_real] = req.tokens[start:start + n_real]
-        logits, self.ws = paged_chunk_fn(self.cfg)(
+        logits, self.ws = paged_chunk_fn(self.cfg, self.mesh)(
             self.params, jnp.asarray(toks), self.ws, jnp.int32(start),
             jnp.int32(n_real))
         self.n_chunk_calls += 1
@@ -781,13 +785,20 @@ class Engine:
         t0 = time.perf_counter()
         L = len(req.tokens)
         self.cache = dict(self.cache)
-        self.cache["pool"] = paged_splice_fn(self.cfg)(
+        self.cache["pool"] = self._placed_pool(paged_splice_fn(self.cfg)(
             self.cache["pool"], self.ws, row_j, jnp.int32(st["hit"]),
-            jnp.int32(L))
+            jnp.int32(L)))
         self.cache["block"] = self.cache["block"].at[slot].set(row_j)
         self.cache["len"] = self.cache["len"].at[slot].set(L)
         self.pkv.insert_prefix(slot, req.tokens)
         self._install_first_token(slot, req, logits, L, t0)
+
+    def _placed_pool(self, pool):
+        """The prefill jits leave the pool's sharding to propagation;
+        under a mesh, put it back on the layout the decode step pins."""
+        if self._pool_sharding is None:
+            return pool
+        return jax.device_put(pool, self._pool_sharding)
 
     def _admit_paged_encdec(self, slot: int, req: Request) -> bool:
         """encdec admission: reserve pages, run ONE bucket-padded prefill
@@ -888,7 +899,7 @@ class Engine:
             ws = paged_hydrate_fn(cfg, self.wws)(
                 self.cache["pool"], zrow, jnp.int32(0))
             for width in self.buckets:
-                lg, ws = paged_chunk_fn(cfg)(
+                lg, ws = paged_chunk_fn(cfg, self.mesh)(
                     self.params, jnp.zeros((1, width), jnp.int32), ws,
                     jnp.int32(0), jnp.int32(width))
             paged_splice_fn(cfg)(self.cache["pool"], ws, zrow,
@@ -899,7 +910,7 @@ class Engine:
                 zb = jnp.zeros((self.capacity, self.n_blocks), jnp.int32)
                 zs = jnp.zeros((self.capacity,), jnp.int32)
                 for width in self.buckets:
-                    paged_packed_fn(cfg, self.wws)(
+                    paged_packed_fn(cfg, self.wws, self.mesh)(
                         self.params, jnp.zeros((1, width), jnp.int32),
                         self.cache["pool"], zb, zs, zs, zs)
         _sample_fn(self.greedy)(lg, self.keys[:1], temp)
@@ -943,11 +954,11 @@ class Engine:
                 out["prefill"] = pf._cache_size()
             out["splice"] = paged_encdec_splice_fn(cfg)._cache_size()
         else:
-            out["chunk"] = paged_chunk_fn(cfg)._cache_size()
+            out["chunk"] = paged_chunk_fn(cfg, self.mesh)._cache_size()
             out["splice"] = paged_splice_fn(cfg)._cache_size()
             out["hydrate"] = paged_hydrate_fn(cfg, self.wws)._cache_size()
             if self.prefill_pack:
-                out["packed"] = paged_packed_fn(cfg, self.wws)._cache_size()
+                out["packed"] = paged_packed_fn(cfg, self.wws, self.mesh)._cache_size()
         return out
 
     # ------------------------------------------------------ static batch

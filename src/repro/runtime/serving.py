@@ -266,40 +266,52 @@ def paged_decode_fn(cfg: ModelConfig, mesh=None, batch: Optional[int] = None,
                                    ops.tuning_fingerprint())
 
 
+def _annotator(cfg: ModelConfig, mesh):
+    """In-trace ``ops.annotate_spmd`` for meshed jits (identity without
+    a mesh): every Pallas kernel under a multi-device jit must run
+    inside a shard_map."""
+    if mesh is None:
+        return lambda params: params
+    axes = api.init_axes(cfg)
+    return lambda params: ops.annotate_spmd(params, axes, mesh)
+
+
 @functools.lru_cache(maxsize=64)
-def _paged_chunk_fn_cached(cfg: ModelConfig, tuning):
+def _paged_chunk_fn_cached(cfg: ModelConfig, mesh, tuning):
     del tuning
     from repro.models import lm as m_lm
 
+    annotate = _annotator(cfg, mesh)
     return jax.jit(lambda params, tokens, ws, start, n_real:
-                   m_lm.lm_paged_prefill_chunk(params, cfg, tokens, ws,
-                                               start, n_real))
+                   m_lm.lm_paged_prefill_chunk(annotate(params), cfg, tokens,
+                                               ws, start, n_real))
 
 
-def paged_chunk_fn(cfg: ModelConfig):
+def paged_chunk_fn(cfg: ModelConfig, mesh=None):
     """One jit for every chunk width: jax re-traces per (1, C) token
     shape, so ``_cache_size()`` counts exactly the bucket widths hit —
     the engine's no-new-traces-after-warmup assertion keys on this."""
-    return _paged_chunk_fn_cached(cfg, ops.tuning_fingerprint())
+    return _paged_chunk_fn_cached(cfg, mesh, ops.tuning_fingerprint())
 
 
 @functools.lru_cache(maxsize=64)
-def _paged_packed_fn_cached(cfg: ModelConfig, wws: int, tuning):
+def _paged_packed_fn_cached(cfg: ModelConfig, wws: int, mesh, tuning):
     del tuning
     from repro.models import lm as m_lm
 
+    annotate = _annotator(cfg, mesh)
     return jax.jit(lambda params, tokens, pool, blocks, bases, hists, lens:
-                   m_lm.lm_paged_prefill_packed(params, cfg, tokens, pool,
-                                                blocks, bases, hists, lens,
-                                                wws))
+                   m_lm.lm_paged_prefill_packed(annotate(params), cfg, tokens,
+                                                pool, blocks, bases, hists,
+                                                lens, wws))
 
 
-def paged_packed_fn(cfg: ModelConfig, wws: int):
+def paged_packed_fn(cfg: ModelConfig, wws: int, mesh=None):
     """Fused packed prefill (hydrate + chunk + splice + per-segment
     logits) for several short prompts in one call. Like
     ``paged_chunk_fn``, jax re-traces per packed (1, C) bucket width —
     ``_cache_size()`` counts exactly the widths hit."""
-    return _paged_packed_fn_cached(cfg, wws, ops.tuning_fingerprint())
+    return _paged_packed_fn_cached(cfg, wws, mesh, ops.tuning_fingerprint())
 
 
 @functools.lru_cache(maxsize=64)

@@ -48,7 +48,7 @@ class TestCacheRoundTrip:
                                "fused", "cpu")
         k2 = autotune.make_key("gemv_packed", 1, 512, 1024, 16, jnp.bfloat16,
                                "packed4", "tpu")
-        tc.put(k1, TileConfig(bm=8, bn=256, bk=512, strategy="gather"))
+        tc.put(k1, TileConfig(bm=8, bn=256, bk=512, strategy="kernel"))
         tc.put(k2, TileConfig(bm=256, bn=128, bk=1024))
         back = TuningCache.from_json_dict(
             json.loads(json.dumps(tc.to_json_dict())))
@@ -102,10 +102,13 @@ class TestTuneSearch:
             autotune.platform_key(True))
 
     def test_all_infeasible_keeps_defaults(self):
-        _, best, _ = autotune.tune(
-            "matmul", M=8, N=72, Kin=96, K=16, interpret=True,
-            measure=lambda tile: float("inf"))
-        assert best == TileConfig(bm=256, bn=256, bk=512)
+        """Nothing is recorded when every candidate fails: the search
+        raises, and the cache keeps serving the default tiles."""
+        tc = TuningCache()
+        with pytest.raises(RuntimeError, match="every candidate failed"):
+            autotune.tune("matmul", M=8, N=72, Kin=96, K=16, interpret=True,
+                          cache=tc, measure=lambda tile: float("inf"))
+        assert len(tc) == 0
 
     def test_interpret_candidates_pin_single_k_step(self):
         """The bit-identity precondition: every interpret candidate keeps
@@ -120,8 +123,8 @@ class TestTuneSearch:
 
 class TestTunedTileParity:
     def test_tuned_fused_tile_is_bit_identical(self):
-        """A non-default tuned tile (gather strategy, small bn) must not
-        change lutq_dot's output bits in interpret mode."""
+        """A non-default tuned tile (small bm/bn) must not change
+        lutq_dot's output bits in interpret mode."""
         M, Kin, N = 5, 96, 72
         st = _serve_state(Kin, N)
         x = jax.random.normal(jax.random.PRNGKey(1), (M, Kin))
@@ -129,8 +132,7 @@ class TestTunedTileParity:
 
         key = autotune.make_key("matmul", M, N, Kin, 16, x.dtype, "fused",
                                 autotune.platform_key(ops._default_interpret()))
-        ops.tuning_cache().put(
-            key, TileConfig(bm=8, bn=32, bk=512, strategy="gather"))
+        ops.tuning_cache().put(key, TileConfig(bm=8, bn=32, bk=512))
         tuned = np.asarray(ops.lutq_dot(x, st, backend="fused"))
         np.testing.assert_array_equal(tuned, default)
         np.testing.assert_allclose(tuned, np.asarray(x @ decode_any(st.d,
@@ -144,14 +146,13 @@ class TestTunedTileParity:
         x = jax.random.normal(jax.random.PRNGKey(1), (M, Kin))
         key = autotune.make_key("matmul", M, N, Kin, 16, x.dtype, "fused",
                                 autotune.platform_key(ops._default_interpret()))
-        ops.tuning_cache().put(
-            key, TileConfig(bm=8, bn=32, bk=512, strategy="gather"))
-        got = ops.lutq_dot(x, st, backend="fused", bm=256, bn=256, bk=512,
-                           strategy="onehot")
+        ops.tuning_cache().put(key, TileConfig(bm=8, bn=32, bk=512))
+        got = ops.lutq_dot(x, st, backend="fused", bm=256, bn=256, bk=512)
+        ops.tuning_cache().clear()
         np.testing.assert_array_equal(
             np.asarray(got),
             np.asarray(ops.lutq_dot(x, st, backend="fused", bm=256, bn=256,
-                                    bk=512, strategy="onehot")))
+                                    bk=512)))
 
     @pytest.mark.slow
     @pytest.mark.parametrize("M,Kin,N", SHAPES)
@@ -163,7 +164,7 @@ class TestTunedTileParity:
         default = np.asarray(ops.lutq_dot(x, st, backend="fused"))
         for t in autotune.candidates("matmul", M, N, Kin, 16, interpret=True):
             got = ops.lutq_dot(x, st, backend="fused", bm=t.bm, bn=t.bn,
-                               bk=t.bk, strategy=t.strategy)
+                               bk=t.bk)
             np.testing.assert_array_equal(np.asarray(got), default, str(t))
         if Kin % 2:
             return
@@ -172,7 +173,7 @@ class TestTunedTileParity:
         for t in autotune.candidates("gemv_packed", M, N, Kin, 16,
                                      interpret=True):
             got = ops.lutq_dot(x, packed, backend="packed4", bm=t.bm,
-                               bn=t.bn, bk=t.bk, strategy=t.strategy)
+                               bn=t.bn, bk=t.bk)
             np.testing.assert_array_equal(np.asarray(got), pdefault, str(t))
 
     @pytest.mark.slow
@@ -206,7 +207,7 @@ class TestPersistence:
 
         key = autotune.make_key("matmul", 8, 128, 64, 16, jnp.float32,
                                 "fused", "cpu")
-        tile = TileConfig(bm=8, bn=128, bk=512, strategy="gather")
+        tile = TileConfig(bm=8, bn=128, bk=512)
         ops.tuning_cache().put(key, tile)
         _, man = serve_view(q, with_manifest=True)
         carried = TuningCache.from_json_dict(man["__tuning_cache__"])
@@ -220,7 +221,7 @@ class TestPersistence:
         q = quantize_tree(_tree(), QuantSpec(bits=4, min_size=1))
         key = autotune.make_key("matmul", 8, 128, 64, 16, jnp.float32,
                                 "fused", "cpu")
-        tile = TileConfig(bm=8, bn=128, bk=512, strategy="gather")
+        tile = TileConfig(bm=8, bn=128, bk=512)
         tc = TuningCache()
         tc.put(key, tile)
         save(q, str(tmp_path), 3, tuning=tc)

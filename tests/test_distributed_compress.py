@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.compress import (
     compressed_allreduce,
@@ -20,7 +19,6 @@ from repro.distributed.compress import (
     init_ef_state,
     ring_allreduce,
 )
-from repro.launch.mesh import compat_make_mesh
 
 multi_device = pytest.mark.skipif(
     len(jax.devices()) < 2,
@@ -90,7 +88,7 @@ class TestEfInt8:
 
 def _ring_mesh():
     n = len(jax.devices())
-    return compat_make_mesh((n,), ("data",)), n
+    return jax.make_mesh((n,), ("data",)), n
 
 
 @multi_device
@@ -98,7 +96,7 @@ class TestRingAllreduce:
     def test_matches_global_sum(self):
         mesh, n = _ring_mesh()
         x = jax.random.normal(jax.random.PRNGKey(0), (n * 4, 16))
-        out = shard_map(lambda v: ring_allreduce(v, "data"), mesh=mesh,
+        out = jax.shard_map(lambda v: ring_allreduce(v, "data"), mesh=mesh,
                         in_specs=P("data"), out_specs=P("data"))(x)
         # every device's local output block equals the sum of all blocks
         want = x.reshape(n, 4, 16).sum(0)
@@ -112,7 +110,7 @@ class TestRingAllreduce:
         internal pad/unpad."""
         mesh, n = _ring_mesh()
         x = jax.random.normal(jax.random.PRNGKey(1), (n * 3, 7))
-        out = shard_map(lambda v: ring_allreduce(v, "data"), mesh=mesh,
+        out = jax.shard_map(lambda v: ring_allreduce(v, "data"), mesh=mesh,
                         in_specs=P("data"), out_specs=P("data"))(x)
         want = x.reshape(n, 3, 7).sum(0)
         got = np.asarray(out).reshape(n, 3, 7)
@@ -122,9 +120,9 @@ class TestRingAllreduce:
     def test_matches_psum(self):
         mesh, n = _ring_mesh()
         x = jax.random.normal(jax.random.PRNGKey(2), (n * 2, 8))
-        ring = shard_map(lambda v: ring_allreduce(v, "data"), mesh=mesh,
+        ring = jax.shard_map(lambda v: ring_allreduce(v, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P("data"))(x)
-        ps = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+        ps = jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                        in_specs=P("data"), out_specs=P("data"))(x)
         np.testing.assert_allclose(np.asarray(ring), np.asarray(ps),
                                    rtol=1e-5, atol=1e-5)
@@ -132,7 +130,7 @@ class TestRingAllreduce:
     def test_compressed_allreduce_approximates_sum(self):
         mesh, n = _ring_mesh()
         x = jax.random.normal(jax.random.PRNGKey(3), (n * 4, 16))
-        out = shard_map(lambda v: compressed_allreduce(v, "data"), mesh=mesh,
+        out = jax.shard_map(lambda v: compressed_allreduce(v, "data"), mesh=mesh,
                         in_specs=P("data"), out_specs=P("data"))(x)
         want = np.asarray(x.reshape(n, 4, 16).sum(0))
         got = np.asarray(out).reshape(n, 4, 16)[0]

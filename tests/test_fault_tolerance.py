@@ -28,8 +28,7 @@ ELASTIC_PHASE1 = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.ckpt import save
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",))
     w = jax.device_put(jnp.arange(32.0).reshape(8, 4),
                        NamedSharding(mesh, P("data", None)))
     # one "training" update on the 4-device mesh
@@ -45,8 +44,7 @@ ELASTIC_PHASE2 = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.ckpt import restore
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None)), "step": None}
     tree, step = restore(sys.argv_dir, shardings=sh)
     assert step == 3

@@ -281,6 +281,39 @@ class TestServeDispatch:
         assert fused_calls == 1, fused_calls
         assert decode_calls > fused_calls
 
+    def test_meshed_dots_keep_their_recorded_sharding(self, monkeypatch):
+        """Scan slices the layer axis off a stacked leaf but not off its
+        serve spec: a row-parallel projection (spec ``P(None, "model")``
+        on its (L, K, N) stack) must reach the shard_map sharded on K,
+        not on N, and a column-parallel one on N."""
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import make_host_mesh
+        from repro.runtime.serving import prefill_fn
+
+        specs = {}
+        real = ops.lutq_dot_spmd
+
+        def spy(x, state, mesh, **kw):
+            specs[state.a.shape] = kw["a_spec"]
+            return real(x, state, mesh, **kw)
+
+        monkeypatch.setattr(ops, "lutq_dot_spmd", spy)
+        cfg, sv, batch = _serve_setup("h2o-danube-1.8b",
+                                      kernel_backend="fused")
+        prefill_fn(cfg, batch["tokens"].shape[1], make_host_mesh(1, 1))(
+            sv, batch)
+
+        def spec(*path):
+            leaf = sv["layers"]
+            for p in path:
+                leaf = leaf[p]
+            return specs[leaf["kernel"].a.shape[1:]]
+
+        # (d_ff differs from d_model, so these two shapes are distinct)
+        assert spec("mlp", "wo") == P("model", None)
+        assert spec("mlp", "wi") == P(None, "model")
+
     def test_generate_backend_kwarg_and_stats(self):
         from repro.runtime.serving import decode_fn, generate
         cfg, sv, batch = _serve_setup("h2o-danube-1.8b")

@@ -44,16 +44,28 @@ class TestLutqMatmul:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=tol, atol=tol)
 
-    def test_take_decode_path(self):
+    def test_select_decode_split_k(self):
+        """The raw kernel's compare-and-select decode over a split
+        reduction (two k steps)."""
         from repro.kernels.lutq_matmul import lutq_matmul as raw
         x = _mk((16, 128), 5)
         a = jax.random.randint(jax.random.PRNGKey(6), (128, 128), 0, 16, jnp.int8)
         d = jnp.sort(_mk((16,), 7))
-        got = raw(x, a, d, bm=16, bn=128, bk=64, decode_onehot=False,
-                  interpret=True)
+        got = raw(x, a, d, bm=16, bn=128, bk=64, interpret=True)
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(lutq_matmul_ref(x, a, d)),
                                    rtol=1e-5, atol=1e-4)
+
+    def test_select_decode_k256_wraps_int8(self):
+        """K=256 assignments stored as int8 (128..255 read as negative)
+        decode like jnp.take's wrap: select_decode is exactly d[a]."""
+        from repro.kernels.lutq_matmul import select_decode, smem_row
+        a = jax.random.randint(jax.random.PRNGKey(8), (64, 128), 0, 256,
+                               jnp.int32).astype(jnp.int8)
+        d = jnp.sort(_mk((256,), 9))
+        got = select_decode(a.astype(jnp.int32), smem_row(d))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(jnp.take(d, a.astype(jnp.int32))))
 
     @given(st.integers(0, 50))
     @settings(max_examples=8, deadline=None)

@@ -126,3 +126,16 @@ class TestArtifacts:
                     ok += 1
                     assert rec["cost"]["flops"] > 0
         assert seen == 40 and ok >= 33
+
+
+class TestHostMesh:
+    def test_refuses_a_mesh_larger_than_the_host(self):
+        """A mesh is never shrunk to the devices present: that would run
+        (and report) a different layout than the one asked for."""
+        from repro.launch.mesh import make_host_mesh
+
+        n = len(jax.devices())
+        with pytest.raises(ValueError, match="needs"):
+            make_host_mesh(1, n + 1)
+        mesh = make_host_mesh(1, n)
+        assert dict(mesh.shape) == {"data": 1, "model": n}

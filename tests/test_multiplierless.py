@@ -111,12 +111,24 @@ class TestShiftKernelParity:
         xq = jax.random.randint(jax.random.PRNGKey(5), (256, 512), -127, 128,
                                 dtype=jnp.int8)
         want = lutq_shift_ref(xq, st_.a, wsh)
-        for strategy in ("onehot", "gather"):
-            got = ops.lutq_shift(xq, st_.a, wsh, bm=256, bn=256, bk=512,
-                                 strategy=strategy)
-            assert got.dtype == jnp.int32
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
-                                          err_msg=strategy)
+        got = ops.lutq_shift(xq, st_.a, wsh, bm=256, bn=256, bk=512)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_raw_kernel_wide_shift_planes(self):
+        """Shifts past 6 bits land in the kernel's higher 7-bit int8
+        planes; the int32 accumulator stays exact."""
+        K = 16
+        wsh = jnp.array([0, 1, -1, 2, -4, 64, 128, -256, 1 << 13,
+                         -(1 << 14), 1 << 16, 512, -8, 16, -(1 << 7), 32],
+                        jnp.int32)[:K]
+        a = jax.random.randint(jax.random.PRNGKey(6), (128, 128), 0, K,
+                               jnp.int8)
+        xq = jax.random.randint(jax.random.PRNGKey(7), (8, 128), -127, 128,
+                                dtype=jnp.int8)
+        got = ops.lutq_shift(xq, a, wsh, bm=8, bn=128, bk=64)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(lutq_shift_ref(xq, a, wsh)))
 
     def test_quant_noise_bounded(self):
         """pow2 output error vs the unquantized-activation product is

@@ -197,14 +197,13 @@ RING_TEST = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     import sys; sys.path.insert(0, "src")
     from repro.distributed.compress import ring_allreduce
 
     mesh = jax.make_mesh((8,), ("data",))
     x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 
-    f = shard_map(lambda s: ring_allreduce(s, "data"), mesh=mesh,
+    f = jax.shard_map(lambda s: ring_allreduce(s, "data"), mesh=mesh,
                   in_specs=P("data", None), out_specs=P("data", None))
     out = jax.jit(f)(x)
     expect = jnp.broadcast_to(x.reshape(8, 1, 8).sum(0), (8, 8))
