@@ -359,6 +359,7 @@ def paged_attention_tpu(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=scratch_bytes + _VMEM_HEADROOM),
         interpret=interpret,
+        name="paged_attn",
     )(block, cl, *operands)
     return out.reshape(B, 1, H, dh)
 

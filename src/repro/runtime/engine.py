@@ -46,7 +46,7 @@ import numpy as np
 from repro.kernels import ops
 from repro.models import api
 from repro.models.config import ModelConfig
-from repro.runtime import paged_kv
+from repro.runtime import paged_kv, tracing
 from repro.runtime.serving import (adapt_prefill_cache, paged_chunk_fn,
                                    paged_encdec_splice_fn, paged_hydrate_fn,
                                    paged_packed_fn, paged_splice_fn,
@@ -128,7 +128,7 @@ def _step_fn_cached(cfg: ModelConfig, greedy: bool, mesh, capacity: int,
     del tuning  # lru salt: tuned tiles are baked into the trace
     axes = api.init_axes(cfg) if mesh is not None else None
 
-    def step(params, tok, cache, keys, temp):
+    def decode_step(params, tok, cache, keys, temp):
         if mesh is not None:
             params = ops.annotate_spmd(params, axes, mesh)
         logits, cache = api.decode_step(params, cfg, tok, cache)
@@ -136,13 +136,13 @@ def _step_fn_cached(cfg: ModelConfig, greedy: bool, mesh, capacity: int,
         return tok, cache, keys
 
     if mesh is None:
-        return jax.jit(step)
+        return jax.jit(decode_step)
     from repro.launch.partition import serve_shardings
 
     sh = serve_shardings(cfg, mesh, batch=capacity, max_len=max_len,
                          src_len=src_len)
     return jax.jit(
-        step,
+        decode_step,
         in_shardings=(None, sh["token"], sh["cache"], sh["keys"], None),
         out_shardings=(sh["token"], sh["cache"], sh["keys"]))
 
@@ -169,7 +169,7 @@ def _paged_step_fn_cached(cfg: ModelConfig, greedy: bool, mesh,
     del tuning
     axes = api.init_axes(cfg) if mesh is not None else None
 
-    def step(params, tok, cache, keys, temp):
+    def paged_decode_step(params, tok, cache, keys, temp):
         if mesh is not None:
             params = ops.annotate_spmd(params, axes, mesh)
         logits, cache = api.paged_decode_step(params, cfg, tok, cache,
@@ -178,14 +178,14 @@ def _paged_step_fn_cached(cfg: ModelConfig, greedy: bool, mesh,
         return tok, cache, keys
 
     if mesh is None:
-        return jax.jit(step)
+        return jax.jit(paged_decode_step)
     from repro.launch.partition import paged_serve_shardings
 
     sh = paged_serve_shardings(cfg, mesh, batch=capacity, n_pages=n_pages,
                                page_size=page_size, n_blocks=n_blocks,
                                src_len=src_len)
     return jax.jit(
-        step,
+        paged_decode_step,
         in_shardings=(None, sh["token"], sh["cache"], sh["keys"], None),
         out_shardings=(sh["token"], sh["cache"], sh["keys"]))
 
@@ -236,6 +236,27 @@ def synthetic_requests(cfg: ModelConfig, n: int, *, max_prompt: int,
         if rate > 0:
             t += float(rng.exponential(1.0 / rate))
     return reqs
+
+
+# cumulative counters that ``Engine.stats()`` reports
+COUNTERS = ("prefill_tokens_real", "prefill_tokens_padded",
+            "admitted_prompt_tokens", "prefix_hit_tokens", "decode_rows",
+            "syncs", "compiles")
+
+
+def _count_prefill(start: int, n_real: int, width: int):
+    """One prefill entry of the step: ``n_real`` prompt tokens from
+    position ``start``, computed ``width`` wide (the rest is padding)."""
+    tracing.note("prefill", (start, n_real, width))
+    tracing.count("prefill_tokens_real", n_real)
+    tracing.count("prefill_tokens_padded", width - n_real)
+
+
+def _count_admitted(prompt_len: int, hit: int):
+    """One admission: its prompt length and the prefix-cache hit."""
+    tracing.note("admitted", (prompt_len, hit))
+    tracing.count("admitted_prompt_tokens", prompt_len)
+    tracing.count("prefix_hit_tokens", hit)
 
 
 @dataclasses.dataclass
@@ -433,8 +454,8 @@ class Engine:
         self._next_rid = 0
         self.n_decode_steps = 0
         self.n_admitted = 0
-        self.t_prefill = 0.0
-        self.t_decode = 0.0
+        # this engine's span time and counters (runtime/tracing.py)
+        self.totals = tracing.Totals()
         self.t_warmup = 0.0
         self._t_start: Optional[float] = None
         if self.paged and warmup:
@@ -542,7 +563,10 @@ class Engine:
             logits, keys, jnp.float32(self.temperature))
         self.tok = self.tok.at[slots_j].set(tok1)
         self.keys = self.keys.at[slots_j].set(keys1)
-        firsts = np.asarray(jax.device_get(tok1[:, 0]))  # one sync per group
+        for L in Ls:
+            _count_admitted(L, 0)
+            _count_prefill(0, L, Lb)
+        firsts = np.asarray(self._sync(tok1[:, 0]))  # one sync per group
 
         now = time.perf_counter()
         for i, (slot, req) in enumerate(zip(slots, reqs)):
@@ -553,7 +577,13 @@ class Engine:
             self.slots[slot] = req           # to the slot's prior occupant
             self.n_admitted += 1
             self._maybe_retire(slot)
-        self.t_prefill += now - t0
+
+    def _sync(self, x):
+        """Wait for ``x`` and copy it to the host: every place the host
+        blocks on the device goes through here (an ``engine.sync``)."""
+        with tracing.span("engine.sync"):
+            tracing.count("syncs")
+            return jax.device_get(x)
 
     def _maybe_retire(self, slot: int):
         req = self.slots[slot]
@@ -561,8 +591,17 @@ class Engine:
         done_len = len(req.out) >= req.max_new
         if not (done_eos or done_len):
             return
-        req.finish = "eos" if done_eos else "length"
+        with tracing.span("engine.retire", rid=req.rid):
+            self._retire(slot, req, "eos" if done_eos else "length")
+
+    def _retire(self, slot: int, req: Request, finish: str):
+        req.finish = finish
         req.t_done = time.perf_counter()
+        # the request's own life, from the times it kept
+        for name, t0, t1 in (("request.queue", req.t_submit, req.t_admit),
+                             ("request.prefill", req.t_admit, req.t_first),
+                             ("request.decode", req.t_first, req.t_done)):
+            tracing.add(name, t0 * 1e9, t1 * 1e9, rid=req.rid)
         self.slots[slot] = None
         # pin the freed slot's position and token so its dead-slot
         # decode writes stay inside the slot (and stay deterministic)
@@ -696,7 +735,6 @@ class Engine:
         """Run one fused packed prefill for an admitted group and
         install every member: per-segment logits row -> first token,
         block row + length -> device cache, prompt -> prefix cache."""
-        t0 = time.perf_counter()
         cfg = self.cfg
         S, NB = self.capacity, self.n_blocks
         kvb = min(cfg.attn_kv_block, self.wws)
@@ -716,26 +754,34 @@ class Engine:
         bases[len(admitted):] = base  # inactive segments park at the end
         tail_tot = sum(len(t) for t in tails)
         C = paged_kv.next_pow2(max(tail_tot, self.buckets[0]))
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :tail_tot] = np.concatenate(tails)
-        logits, pool = paged_packed_fn(cfg, self.wws, self.mesh)(
-            self.params, jnp.asarray(toks), self.cache["pool"],
-            jnp.asarray(blocks), jnp.asarray(bases), jnp.asarray(hists),
-            jnp.asarray(lens))
+        with tracing.span("engine.prefill_packed",
+                          rids=[a[1].rid for a in admitted], tail=tail_tot,
+                          width=C):
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :tail_tot] = np.concatenate(tails)
+            logits, pool = paged_packed_fn(cfg, self.wws, self.mesh)(
+                self.params, jnp.asarray(toks), self.cache["pool"],
+                jnp.asarray(blocks), jnp.asarray(bases), jnp.asarray(hists),
+                jnp.asarray(lens))
+            # one entry per segment; the call's padding is the last one's
+            for s, (_, req, _, hit) in enumerate(admitted):
+                L, last = len(req.tokens), s == len(admitted) - 1
+                _count_admitted(L, hit)
+                _count_prefill(hit, L - hit,
+                               L - hit + (C - tail_tot if last else 0))
         self.n_chunk_calls += 1
         self.n_packed_groups += 1
         self.n_packed_reqs += len(admitted)
         self.cache = dict(self.cache)
         self.cache["pool"] = self._placed_pool(pool)
         for s, (slot, req, row, hit) in enumerate(admitted):
-            L = len(req.tokens)
-            self.cache["block"] = self.cache["block"].at[slot].set(
-                jnp.asarray(row))
-            self.cache["len"] = self.cache["len"].at[slot].set(L)
-            self.pkv.insert_prefix(slot, req.tokens)
-            # t_prefill is charged once (s=0 spans the packed call)
-            self._install_first_token(slot, req, logits[s][None], L,
-                                      t0 if s == 0 else time.perf_counter())
+            with tracing.span("engine.finish_prefill", rid=req.rid):
+                L = len(req.tokens)
+                self.cache["block"] = self.cache["block"].at[slot].set(
+                    jnp.asarray(row))
+                self.cache["len"] = self.cache["len"].at[slot].set(L)
+                self.pkv.insert_prefix(slot, req.tokens)
+                self._install_first_token(slot, req, logits[s][None], L)
 
     def _start_chunking(self, slot: int, req: Request, row: np.ndarray,
                         hit_tokens: int):
@@ -747,31 +793,32 @@ class Engine:
         hit, and to zeros otherwise — so chunk inputs never depend on a
         previous request's leftovers (masked garbage is a numeric no-op,
         but a deterministic workspace keeps replays bit-stable)."""
-        t0 = time.perf_counter()
-        row_j = jnp.asarray(row)
-        self.ws = paged_hydrate_fn(self.cfg, self.wws)(
-            self.cache["pool"], row_j, jnp.int32(hit_tokens))
+        _count_admitted(len(req.tokens), hit_tokens)
+        with tracing.span("engine.hydrate", rid=req.rid):
+            row_j = jnp.asarray(row)
+            self.ws = paged_hydrate_fn(self.cfg, self.wws)(
+                self.cache["pool"], row_j, jnp.int32(hit_tokens))
         self._chunking = {
             "req": req, "slot": slot, "row": row_j, "hit": hit_tokens,
             "plan": paged_kv.chunk_plan(len(req.tokens), hit_tokens,
                                         self.max_chunk),
             "i": 0,
         }
-        self.t_prefill += time.perf_counter() - t0
 
     def _chunk_step(self):
         st = self._chunking
-        t0 = time.perf_counter()
         start, width, n_real = st["plan"][st["i"]]
         req = st["req"]
-        toks = np.zeros((1, width), np.int32)
-        toks[0, :n_real] = req.tokens[start:start + n_real]
-        logits, self.ws = paged_chunk_fn(self.cfg, self.mesh)(
-            self.params, jnp.asarray(toks), self.ws, jnp.int32(start),
-            jnp.int32(n_real))
+        with tracing.span("engine.prefill_chunk", rid=req.rid, start=start,
+                          n_real=n_real, width=width):
+            _count_prefill(start, n_real, width)
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :n_real] = req.tokens[start:start + n_real]
+            logits, self.ws = paged_chunk_fn(self.cfg, self.mesh)(
+                self.params, jnp.asarray(toks), self.ws, jnp.int32(start),
+                jnp.int32(n_real))
         self.n_chunk_calls += 1
         st["i"] += 1
-        self.t_prefill += time.perf_counter() - t0
         if st["i"] == len(st["plan"]):
             self._finish_chunking(logits)
 
@@ -782,16 +829,16 @@ class Engine:
         sample the first token."""
         st, self._chunking = self._chunking, None
         req, slot, row_j = st["req"], st["slot"], st["row"]
-        t0 = time.perf_counter()
-        L = len(req.tokens)
-        self.cache = dict(self.cache)
-        self.cache["pool"] = self._placed_pool(paged_splice_fn(self.cfg)(
-            self.cache["pool"], self.ws, row_j, jnp.int32(st["hit"]),
-            jnp.int32(L)))
-        self.cache["block"] = self.cache["block"].at[slot].set(row_j)
-        self.cache["len"] = self.cache["len"].at[slot].set(L)
-        self.pkv.insert_prefix(slot, req.tokens)
-        self._install_first_token(slot, req, logits, L, t0)
+        with tracing.span("engine.finish_prefill", rid=req.rid):
+            L = len(req.tokens)
+            self.cache = dict(self.cache)
+            self.cache["pool"] = self._placed_pool(paged_splice_fn(self.cfg)(
+                self.cache["pool"], self.ws, row_j, jnp.int32(st["hit"]),
+                jnp.int32(L)))
+            self.cache["block"] = self.cache["block"].at[slot].set(row_j)
+            self.cache["len"] = self.cache["len"].at[slot].set(L)
+            self.pkv.insert_prefix(slot, req.tokens)
+            self._install_first_token(slot, req, logits, L)
 
     def _placed_pool(self, pool):
         """The prefill jits leave the pool's sharding to propagation;
@@ -814,61 +861,65 @@ class Engine:
         row, _ = got
         req.t_admit = time.perf_counter()
         req.kv_pages = len(self.pkv.rows[slot])
-        t0 = time.perf_counter()
         L = len(req.tokens)
         Lb = paged_kv.next_pow2(max(L, self.buckets[0]))
-        toks = np.zeros((1, Lb), np.int32)
-        toks[0, :L] = req.tokens
-        batch = {"tokens": jnp.asarray(toks),
-                 "frames": jnp.asarray(req.frames)[None]}
-        logits, pcache = prefill_fn(self.cfg, self.max_len, self.mesh)(
-            self.params, batch, jnp.asarray([L], jnp.int32))
-        row_j = jnp.asarray(row)
-        self.cache = dict(paged_encdec_splice_fn(self.cfg)(
-            self.cache, pcache["layers"], row_j, jnp.int32(L),
-            jnp.int32(slot)))
-        self.cache["block"] = self.cache["block"].at[slot].set(row_j)
-        self.cache["len"] = self.cache["len"].at[slot].set(L)
-        self.cache["src_len"] = self.cache["src_len"].at[slot].set(
-            req.frames.shape[0])
-        self._install_first_token(slot, req, logits, L, t0)
+        _count_admitted(L, 0)
+        with tracing.span("engine.prefill_chunk", rid=req.rid, start=0,
+                          n_real=L, width=Lb):
+            _count_prefill(0, L, Lb)
+            toks = np.zeros((1, Lb), np.int32)
+            toks[0, :L] = req.tokens
+            batch = {"tokens": jnp.asarray(toks),
+                     "frames": jnp.asarray(req.frames)[None]}
+            logits, pcache = prefill_fn(self.cfg, self.max_len, self.mesh)(
+                self.params, batch, jnp.asarray([L], jnp.int32))
+        with tracing.span("engine.finish_prefill", rid=req.rid):
+            row_j = jnp.asarray(row)
+            self.cache = dict(paged_encdec_splice_fn(self.cfg)(
+                self.cache, pcache["layers"], row_j, jnp.int32(L),
+                jnp.int32(slot)))
+            self.cache["block"] = self.cache["block"].at[slot].set(row_j)
+            self.cache["len"] = self.cache["len"].at[slot].set(L)
+            self.cache["src_len"] = self.cache["src_len"].at[slot].set(
+                req.frames.shape[0])
+            self._install_first_token(slot, req, logits, L)
         return True
 
     def _install_first_token(self, slot: int, req: Request, logits,
-                             length: int, t0: float):
+                             length: int):
         """Shared admission tail: sample token 1, arm the slot, retire
         immediately if max_new == 1 or the first token is EOS."""
         tok1, keys1 = _sample_fn(self.greedy)(
             logits, req.key[None], jnp.float32(self.temperature))
         self.tok = self.tok.at[slot].set(tok1[0])
         self.keys = self.keys.at[slot].set(keys1[0])
-        first = int(np.asarray(jax.device_get(tok1))[0, 0])
-        now = time.perf_counter()
-        req.t_first = now
+        first = int(np.asarray(self._sync(tok1))[0, 0])
+        req.t_first = time.perf_counter()
         req.out = [first]
         req.pstart = len(self._pending)
         self.slots[slot] = req
         self.n_admitted += 1
         self.pkv.lens[slot] = length
         self._maybe_retire(slot)
-        self.t_prefill += now - t0
 
     def _release_window_pages(self):
         """Sliding-window decode never reads KV behind ``len - window``:
         free those pages (refcount-aware — shared prefix pages stay) and
         zero their device block entries so the freed physical pages
         can't be read or written through stale rows."""
-        updates = []
-        for slot, r in enumerate(self.slots):
-            if r is None:
-                continue
-            for j in self.pkv.release_behind_window(slot, self.cfg.window):
-                updates.append((slot, j))
-        if updates:
-            rows = jnp.asarray([u[0] for u in updates], jnp.int32)
-            cols = jnp.asarray([u[1] for u in updates], jnp.int32)
-            self.cache = dict(self.cache)
-            self.cache["block"] = self.cache["block"].at[rows, cols].set(0)
+        with tracing.span("engine.release_window"):
+            updates = []
+            for slot, r in enumerate(self.slots):
+                if r is None:
+                    continue
+                for j in self.pkv.release_behind_window(slot,
+                                                        self.cfg.window):
+                    updates.append((slot, j))
+            if updates:
+                rows = jnp.asarray([u[0] for u in updates], jnp.int32)
+                cols = jnp.asarray([u[1] for u in updates], jnp.int32)
+                self.cache = dict(self.cache)
+                self.cache["block"] = self.cache["block"].at[rows, cols].set(0)
 
     def _warm_paged(self):
         """AOT-warm every jit the paged engine can hit, closing the trace
@@ -984,31 +1035,35 @@ class Engine:
         if B != self.capacity:
             raise ValueError(f"batch {B} != capacity {self.capacity}")
         t0 = time.perf_counter()
-        lengths_j = (jnp.full((B,), P, jnp.int32) if lengths is None
-                     else jnp.asarray(lengths, jnp.int32))
-        pf = prefill_fn(self.cfg, self.max_len, self.mesh)
-        if lengths is None:
-            logits, cache = pf(self.params, batch)
-        else:
-            logits, cache = pf(self.params, batch, lengths_j)
-        pfx = self.prefix if "prefix_embeds" in batch else 0
-        self.cache = adapt_prefill_cache(
-            self.cfg, cache, B, self.max_len, src_len=self.src_len,
-            lengths=lengths_j + pfx)
-        if self.mesh is not None:
-            from repro.launch.partition import serve_shardings
+        with tracing.span("engine.admit", totals=self.totals):
+            lengths_j = (jnp.full((B,), P, jnp.int32) if lengths is None
+                         else jnp.asarray(lengths, jnp.int32))
+            pf = prefill_fn(self.cfg, self.max_len, self.mesh)
+            if lengths is None:
+                logits, cache = pf(self.params, batch)
+            else:
+                logits, cache = pf(self.params, batch, lengths_j)
+            pfx = self.prefix if "prefix_embeds" in batch else 0
+            self.cache = adapt_prefill_cache(
+                self.cfg, cache, B, self.max_len, src_len=self.src_len,
+                lengths=lengths_j + pfx)
+            if self.mesh is not None:
+                from repro.launch.partition import serve_shardings
 
-            sh = serve_shardings(self.cfg, self.mesh, batch=self.capacity,
-                                 max_len=self.max_len, src_len=self.src_len)
-            self.cache = jax.device_put(self.cache, sh["cache"])
-        tok1, keys = _sample_fn(self.greedy)(
-            logits, self.keys, jnp.float32(self.temperature))
-        self.tok, self.keys = tok1, keys
-        firsts = np.asarray(jax.device_get(tok1[:, 0]))
-        self.t_prefill += time.perf_counter() - t0
+                sh = serve_shardings(self.cfg, self.mesh, batch=self.capacity,
+                                     max_len=self.max_len,
+                                     src_len=self.src_len)
+                self.cache = jax.device_put(self.cache, sh["cache"])
+            tok1, keys = _sample_fn(self.greedy)(
+                logits, self.keys, jnp.float32(self.temperature))
+            self.tok, self.keys = tok1, keys
+            firsts = np.asarray(self._sync(tok1[:, 0]))
+            lens_h = np.asarray(jax.device_get(lengths_j))
+            for L in lens_h:
+                _count_admitted(int(L), 0)
+                _count_prefill(0, int(L), P)
 
         now = time.perf_counter()
-        lens_h = np.asarray(jax.device_get(lengths_j))
         toks_h = np.asarray(jax.device_get(toks), np.int32)
         for i in range(B):
             req = Request(self._next_rid, toks_h[i, :int(lens_h[i])],
@@ -1029,18 +1084,19 @@ class Engine:
         run the retirement checks they unlock."""
         if not self._pending:
             return
-        vals = np.asarray(jax.device_get(jnp.stack(self._pending)))  # (k, B)
-        k = len(self._pending)
-        self._pending = []
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            for j in range(req.pstart, k):
-                req.out.append(int(vals[j, slot]))
-            req.pstart = 0
-        for slot in range(self.capacity):
-            if self.slots[slot] is not None:
-                self._maybe_retire(slot)
+        with tracing.span("engine.materialize"):
+            vals = np.asarray(self._sync(jnp.stack(self._pending)))  # (k, B)
+            k = len(self._pending)
+            self._pending = []
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                for j in range(req.pstart, k):
+                    req.out.append(int(vals[j, slot]))
+                req.pstart = 0
+            for slot in range(self.capacity):
+                if self.slots[slot] is not None:
+                    self._maybe_retire(slot)
 
     def _spec_round(self):
         """One speculative round over all slots: k draft steps with the
@@ -1065,8 +1121,8 @@ class Engine:
         # next pending token = last accepted (its KV is not written yet)
         self.tok = jnp.take_along_axis(
             out, (n_acc - 1)[:, None], axis=1).astype(jnp.int32)
-        out_h = np.asarray(jax.device_get(out))     # (B, k+1)
-        acc_h = np.asarray(jax.device_get(n_acc))   # (B,)
+        # (B, k+1) tokens and (B,) accepted counts
+        out_h, acc_h = map(np.asarray, self._sync((out, n_acc)))
         self.n_spec_rounds += 1
         for slot, req in enumerate(self.slots):
             if req is None:
@@ -1102,52 +1158,64 @@ class Engine:
         if self._t_start is None:
             self._t_start = time.perf_counter()
         before = set(self.results)
-        if self.paged:
-            self._paged_admit()
-        elif self.queue and None in self.slots:
-            free = [i for i, s in enumerate(self.slots) if s is None]
-            take = [self.queue.popleft()
-                    for _ in range(min(len(free), len(self.queue)))]
-            groups: Dict[Any, List[Request]] = {}
-            for req in take:
-                groups.setdefault(self._group_key(req), []).append(req)
-            for reqs in groups.values():
-                slots, free = free[:len(reqs)], free[len(reqs):]
-                self._admit_group(slots, reqs)
-        active = [r for r in self.slots if r is not None]
-        if active and self.spec_k:
-            t0 = time.perf_counter()
-            self._spec_round()
-            self.t_decode += time.perf_counter() - t0
-            self.n_decode_steps += 1
-        elif active:
-            t0 = time.perf_counter()
-            if self.paged:
-                fn = _paged_step_fn(self.cfg, self.greedy, self.mesh,
-                                    self.capacity, self.n_pages,
-                                    self.page_size, self.n_blocks,
-                                    self.src_len)
-            else:
-                fn = _step_fn(self.cfg, self.greedy, self.mesh,
-                              self.capacity, self.max_len, self.src_len)
-            self.tok, self.cache, self.keys = fn(
-                self.params, self.tok, self.cache, self.keys,
-                jnp.float32(self.temperature))
-            self._pending.append(self.tok[:, 0])
-            if self.paged:
-                for i, r in enumerate(self.slots):
-                    if r is not None:
-                        self.pkv.lens[i] += 1
-                if self.cfg.window is not None:
-                    self._release_window_pages()
-            n_pend = len(self._pending)
-            if (any(r.eos_id is not None for r in active)
-                    or any(len(r.out) + n_pend - r.pstart >= r.max_new
-                           for r in active)):
-                self._materialize()
-            self.t_decode += time.perf_counter() - t0
-            self.n_decode_steps += 1
+        with tracing.span("engine.step", totals=self.totals):
+            if self._chunking is not None or (self.queue
+                                              and None in self.slots):
+                with tracing.span("engine.admit"):
+                    if self.paged:
+                        self._paged_admit()
+                    else:
+                        self._admit_slots()
+            active = [r for r in self.slots if r is not None]
+            if active:
+                tracing.count("decode_rows", len(active))
+                if self.spec_k:
+                    with tracing.span("engine.spec_round", rows=len(active)):
+                        self._spec_round()
+                else:
+                    with tracing.span("engine.decode", rows=len(active)):
+                        self._decode(active)
+                self.n_decode_steps += 1
         return [self.results[r] for r in sorted(set(self.results) - before)]
+
+    def _admit_slots(self):
+        """Slot-pool admission: take queue heads into the free slots,
+        one batched prefill per compatible group."""
+        free = [i for i, s in enumerate(self.slots) if s is None]
+        take = [self.queue.popleft()
+                for _ in range(min(len(free), len(self.queue)))]
+        groups: Dict[Any, List[Request]] = {}
+        for req in take:
+            groups.setdefault(self._group_key(req), []).append(req)
+        for reqs in groups.values():
+            slots, free = free[:len(reqs)], free[len(reqs):]
+            self._admit_group(slots, reqs)
+
+    def _decode(self, active: List[Request]):
+        """Dispatch one decode step over every slot, then materialize
+        the pending tokens if a retirement decision needs them."""
+        if self.paged:
+            fn = _paged_step_fn(self.cfg, self.greedy, self.mesh,
+                                self.capacity, self.n_pages, self.page_size,
+                                self.n_blocks, self.src_len)
+        else:
+            fn = _step_fn(self.cfg, self.greedy, self.mesh,
+                          self.capacity, self.max_len, self.src_len)
+        self.tok, self.cache, self.keys = fn(
+            self.params, self.tok, self.cache, self.keys,
+            jnp.float32(self.temperature))
+        self._pending.append(self.tok[:, 0])
+        if self.paged:
+            for i, r in enumerate(self.slots):
+                if r is not None:
+                    self.pkv.lens[i] += 1
+            if self.cfg.window is not None:
+                self._release_window_pages()
+        n_pend = len(self._pending)
+        if (any(r.eos_id is not None for r in active)
+                or any(len(r.out) + n_pend - r.pstart >= r.max_new
+                       for r in active)):
+            self._materialize()
 
     @property
     def idle(self) -> bool:
@@ -1174,6 +1242,13 @@ class Engine:
     # ------------------------------------------------------------- stats
 
     def stats(self) -> Dict[str, Any]:
+        """Counts and times of this engine so far.
+
+        ``t_prefill_s`` sums the recorder's ``engine.admit`` spans and
+        ``t_decode_s`` its ``engine.decode`` and ``engine.spec_round``
+        spans (``runtime/tracing.py``): host-side times, which include
+        the device syncs they waited on, and read 0 with the recorder
+        off. The ``COUNTERS`` are cumulative."""
         done = list(self.results.values())
         new_toks = sum(r["n_new"] for r in done)
         # first tokens come from prefill; decode produced the rest
@@ -1182,6 +1257,7 @@ class Engine:
         ttft = sorted(r["t_first_token_s"] for r in done) or [0.0]
         wall = ((time.perf_counter() - self._t_start)
                 if self._t_start is not None else 0.0)
+        t_decode = self.totals.seconds("engine.decode", "engine.spec_round")
         out = {
             "capacity": self.capacity,
             "max_len": self.max_len,
@@ -1192,10 +1268,10 @@ class Engine:
             "completed": len(done),
             "decode_steps": self.n_decode_steps,
             "new_tokens": new_toks,
-            "t_prefill_s": self.t_prefill,
-            "t_decode_s": self.t_decode,
+            "t_prefill_s": self.totals.seconds("engine.admit"),
+            "t_decode_s": t_decode,
             "wall_s": wall,
-            "decode_tok_s": decoded / max(self.t_decode, 1e-9),
+            "decode_tok_s": decoded / t_decode if t_decode > 0 else 0.0,
             "goodput_tok_s": new_toks / max(wall, 1e-9),
             "tokens_per_engine_step": decoded / max(self.n_decode_steps, 1),
             "p50_latency_s": float(np.percentile(lat, 50)),
@@ -1204,6 +1280,7 @@ class Engine:
             "ttft_p99_s": float(np.percentile(ttft, 99)),
             "paged": self.paged,
         }
+        out.update({k: self.totals.counts.get(k, 0) for k in COUNTERS})
         if self.spec_k:
             live = max(self._spec_live, 1)
             out["speculative_k"] = self.spec_k

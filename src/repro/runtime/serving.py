@@ -282,9 +282,12 @@ def _paged_chunk_fn_cached(cfg: ModelConfig, mesh, tuning):
     from repro.models import lm as m_lm
 
     annotate = _annotator(cfg, mesh)
-    return jax.jit(lambda params, tokens, ws, start, n_real:
-                   m_lm.lm_paged_prefill_chunk(annotate(params), cfg, tokens,
-                                               ws, start, n_real))
+
+    def paged_prefill_chunk(params, tokens, ws, start, n_real):
+        return m_lm.lm_paged_prefill_chunk(annotate(params), cfg, tokens, ws,
+                                           start, n_real)
+
+    return jax.jit(paged_prefill_chunk)
 
 
 def paged_chunk_fn(cfg: ModelConfig, mesh=None):
@@ -300,10 +303,14 @@ def _paged_packed_fn_cached(cfg: ModelConfig, wws: int, mesh, tuning):
     from repro.models import lm as m_lm
 
     annotate = _annotator(cfg, mesh)
-    return jax.jit(lambda params, tokens, pool, blocks, bases, hists, lens:
-                   m_lm.lm_paged_prefill_packed(annotate(params), cfg, tokens,
-                                                pool, blocks, bases, hists,
-                                                lens, wws))
+
+    def paged_prefill_packed(params, tokens, pool, blocks, bases, hists,
+                             lens):
+        return m_lm.lm_paged_prefill_packed(annotate(params), cfg, tokens,
+                                            pool, blocks, bases, hists, lens,
+                                            wws)
+
+    return jax.jit(paged_prefill_packed)
 
 
 def paged_packed_fn(cfg: ModelConfig, wws: int, mesh=None):
@@ -325,8 +332,10 @@ def paged_splice_fn(cfg: ModelConfig):
 def paged_hydrate_fn(cfg: ModelConfig, wws: int):
     from repro.models import lm as m_lm
 
-    return jax.jit(lambda pool, row, hist:
-                   m_lm.lm_paged_hydrate(cfg, pool, row, hist, wws))
+    def paged_hydrate(pool, row, hist):
+        return m_lm.lm_paged_hydrate(cfg, pool, row, hist, wws)
+
+    return jax.jit(paged_hydrate)
 
 
 @functools.lru_cache(maxsize=64)
@@ -376,8 +385,9 @@ def generate(
     | packed4) applied as ``cfg.replace(kernel_backend=...)``, so serve
     trees hit the requested Pallas LUT-Q path. ``return_stats=True``
     additionally returns {"t_prefill_s", "t_decode_s", "decode_tok_s",
-    "backend"} measured around the jit-cached entry points (the same
-    ones the CLI times, so library and CLI numbers agree).
+    "backend"} from the engine's ``engine.admit`` and ``engine.decode``
+    spans (``runtime/tracing.py``; the same ones the CLI prints, so
+    library and CLI numbers agree).
 
     ``mesh``: optional ``("data", "model")`` device mesh for SPMD
     serving — params should already be placed (``shard_serve_params``);
