@@ -11,9 +11,12 @@ drop 2-4x (4x more with the packed 4-bit variant in lutq_gemv_packed),
 which moves the decode-phase memory roofline term directly.
 
 Decode is a compare-and-select over the K dictionary scalars on the
-(bk, bn) tile (:func:`select_decode`). Mosaic lowers neither a 1-D
-gather nor the ``(bk*bn, 1)`` reshape a one-hot matmul needs; the
-select chain yields exactly ``d[a]``.
+(bk, bn) tile (:func:`select_decode`); it yields exactly ``d[a]``.
+Mosaic lowers neither a 1-D gather nor the ``(bk*bn, 1)`` reshape a
+one-hot matmul needs. It does lower a 2-D gather along lanes, but only
+from a table one vreg (128 lanes) wide: a 16-entry dictionary fits (the
+packed 4-bit kernel's decodes were timed, see ``docs/kernels.md``), a
+256-entry one does not.
 
 Grid: (M/bm, N/bn, Kin/bk), k innermost so the f32 output block stays
 resident across the accumulation.

@@ -63,6 +63,9 @@ BACKENDS = ("auto", "decode", "fused", "packed4", "pow2")
 #: Default tiles when the tuning cache has no entry for a shape.
 DEFAULT_TILE = TileConfig(bm=256, bn=256, bk=512)
 
+#: Upper bounds of the ``packed4`` default tile (:func:`default_tile`).
+PACKED_TILE_CAP = TileConfig(bm=256, bn=1024, bk=2560)
+
 # process-level tuning cache: ``lutq_dot`` consults it at trace time,
 # ``--autotune cache|search`` fills it, ``serve_view`` / checkpoints
 # persist it. Its monotonic version feeds the serving-jit lru keys (via
@@ -199,12 +202,40 @@ def _tile(dim: int, block: int, base: int):
     return t, _round_up(dim, t)
 
 
+def _divisor_tile(dim: int, cap: int, base: int) -> int:
+    """The largest multiple of ``base`` up to ``cap`` that divides
+    ``dim`` rounded up to ``base``."""
+    padded = _round_up(dim, base)
+    t = max(base, min(cap, padded) // base * base)
+    while padded % t:
+        t -= base
+    return t
+
+
+def default_tile(be: str, N: int, Kin: int) -> TileConfig:
+    """Tile of a kernel shape that the tuning cache does not hold.
+
+    ``packed4`` takes the widest bn (a multiple of 128 lanes) and bk (of
+    256, so each nibble plane's x block spans whole lanes) under
+    :data:`PACKED_TILE_CAP` that divide N and Kin, rounded up to those
+    multiples: on a v5e the wider grid steps ran faster, and a dividing
+    tile pads nothing beyond the hardware tiling. bm holds a decode batch
+    whole, and tiles a prefill so that x and the f32 output stay inside
+    scoped VMEM. The other kernels take :data:`DEFAULT_TILE`."""
+    if be != "packed4":
+        return DEFAULT_TILE
+    cap = PACKED_TILE_CAP
+    return TileConfig(bm=cap.bm, bn=_divisor_tile(N, cap.bn, 128),
+                      bk=_divisor_tile(Kin, cap.bk, 256))
+
+
 def _tuned_tile(be: str, M: int, N: int, Kin: int, K: int, dtype,
                 interpret: bool) -> TileConfig:
-    """Cache lookup for one kernel shape; defaults when absent."""
+    """Cache lookup for one kernel shape; :func:`default_tile` when
+    absent."""
     key = make_key(KERNEL_OF_BACKEND[be], M, N, Kin, K, dtype, be,
                    platform_key(interpret))
-    return _TUNING_CACHE.get(key) or DEFAULT_TILE
+    return _TUNING_CACHE.get(key) or default_tile(be, N, Kin)
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +328,7 @@ def lutq_dot(
     training STE). Returns (..., N) in ``out_dtype`` (default x.dtype).
 
     Tile sizes default to the process :class:`TuningCache` entry for
-    this (kernel, shape, dtype, platform) key — :data:`DEFAULT_TILE`
+    this (kernel, shape, dtype, platform) key — :func:`default_tile`
     when untuned. Explicit ``bm/bn/bk`` arguments override the cache
     field-by-field. Callers that
     jit around ``lutq_dot`` must salt their jit/lru keys with

@@ -414,7 +414,8 @@ def main(argv=None):
                 rec["kernel"], rec["M"], rec["N"], rec["Kin"], rec["K"],
                 cfg.dtype, rec["backend"],
                 autotune.platform_key(ops._default_interpret()))
-            tile = tc.get(key) or ops.DEFAULT_TILE
+            tile = tc.get(key) or ops.default_tile(rec["backend"], rec["N"],
+                                                   rec["Kin"])
             for path in rec["paths"]:
                 print(f"[serve]   tile {path}: {rec['backend']} "
                       f"bm={tile.bm} bn={tile.bn} bk={tile.bk}"

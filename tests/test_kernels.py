@@ -90,13 +90,17 @@ class TestPacking:
 
 
 class TestGemvPacked:
+    @pytest.mark.parametrize("K", [16, 8, 3])
     @pytest.mark.parametrize("B,Kin,N", [(1, 256, 256), (8, 512, 128),
-                                         (16, 1024, 512)])
-    def test_matches_ref(self, B, Kin, N):
+                                         (16, 1024, 512), (32, 512, 384),
+                                         (256, 256, 256)])
+    def test_matches_ref(self, B, Kin, N, K):
+        """Dictionaries of 16, 8 and 3 entries (the padded entries are
+        never indexed), decode to prefill batches, two k steps."""
         x = _mk((B, Kin), 1)
-        a = jax.random.randint(jax.random.PRNGKey(2), (Kin, N), 0, 16, jnp.int8)
+        a = jax.random.randint(jax.random.PRNGKey(2), (Kin, N), 0, K, jnp.int8)
         packed = pack4(a)
-        d = jnp.sort(_mk((16,), 3))
+        d = jnp.sort(_mk((K,), 3))
         got = ops.lutq_gemv_packed(x, packed, d, bn=128, bk=128, interpret=True)
         want = lutq_gemv_packed_ref(x, packed, d)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -105,6 +109,55 @@ class TestGemvPacked:
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(lutq_matmul_ref(x, a, d)),
                                    rtol=1e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("K", [16, 8, 3])
+    @pytest.mark.parametrize("bn,bk", [(128, 128), (256, 512)])
+    def test_decodes_d_of_a_bit_for_bit(self, K, bn, bk):
+        """Identity rows read the decoded tile back: each nibble plane
+        decodes to exactly ``d[a]`` in x's dtype, whatever the tile."""
+        Kin, N = 512, 256
+        a = jax.random.randint(jax.random.PRNGKey(4), (Kin, N), 0, K, jnp.int8)
+        d = jnp.sort(_mk((K,), 5))
+        got = ops.lutq_gemv_packed(jnp.eye(Kin, dtype=jnp.bfloat16), pack4(a),
+                                   d, bn=bn, bk=bk, interpret=True)
+        want = jnp.take(d, a.astype(jnp.int32)).astype(jnp.bfloat16)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want.astype(jnp.float32)))
+
+    @pytest.mark.parametrize("M,Kin,N", [(5, 300, 200), (33, 130, 57)])
+    def test_lutq_dot_pads_onto_the_grid(self, M, Kin, N):
+        """``lutq_dot`` zero-pads rows, the reduction and the output
+        lanes onto the kernel's tiles and slices the pad off."""
+        from repro.core.lutq import LutqState
+        from repro.kernels.ref import pack4_kin
+        x = _mk((M, Kin), 6)
+        a = jax.random.randint(jax.random.PRNGKey(7), (Kin, N), 0, 16, jnp.int8)
+        d = jnp.sort(_mk((16,), 8))
+        st = LutqState(w=None, d=d, a=pack4_kin(a))
+        got = ops.lutq_dot(x, st, backend="packed4", bm=8, bn=128, bk=256,
+                           interpret=True)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(lutq_matmul_ref(x, a, d)),
+                                   rtol=1e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("N,Kin,bn,bk", [
+        (6912, 2560, 768, 2560),      # danube gate/up
+        (2560, 6912, 640, 2304),      # danube down
+        (32000, 2560, 640, 2560),     # danube head
+        (14336, 5120, 1024, 2560),    # Nemo gate/up
+        (5120, 14336, 1024, 2048),    # Nemo down
+        (131072, 5120, 1024, 2560),   # Nemo head
+        (57, 130, 128, 256),          # padded up to the hardware tiling
+    ])
+    def test_default_tile_divides_the_shape(self, N, Kin, bn, bk):
+        """The packed default tile is the widest under the cap that
+        divides N and Kin (rounded up to 128 lanes and 256 rows): no
+        grid step decodes padding beyond the hardware tiling."""
+        t = ops.default_tile("packed4", N, Kin)
+        assert (t.bn, t.bk) == (bn, bk)
+        assert -(-N // 128) * 128 % t.bn == 0 and t.bn % 128 == 0
+        assert -(-Kin // 256) * 256 % t.bk == 0 and t.bk % 256 == 0
+        assert ops.default_tile("fused", N, Kin) == ops.DEFAULT_TILE
 
     def test_weight_bytes_are_quartered(self):
         Kin, N = 512, 256

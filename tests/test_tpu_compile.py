@@ -5,7 +5,8 @@ tiling rules or its VMEM limit. The TPU compiler installed with jax
 compiles for a v5e that is described rather than attached, so these
 tests compile each raw kernel with ``interpret=False`` on shapes alone,
 at h2o-danube-1.8b widths (d_model 2560, d_ff 6912, 8 KV heads of 80,
-pages of 64 over a 4096-token window), and check that the program holds
+pages of 64 over a 4096-token window), the packed GEMV also at
+Mistral-Nemo-12B's, and check that the program holds
 a Mosaic kernel. Nothing runs: a pass says the chip's compiler accepts
 the kernel, not that it is fast or right.
 
@@ -26,11 +27,13 @@ from repro.kernels.kmeans_tpu import kmeans_stats
 from repro.kernels.lutq_gemv_packed import lutq_gemv_packed
 from repro.kernels.lutq_matmul import lutq_matmul
 from repro.kernels.lutq_shift import lutq_shift
+from repro.kernels.ops import default_tile
 from repro.kernels.paged_attn import paged_attention_tpu, vmem_plan
 
 D_MODEL, D_FF, LAYERS = 2560, 6912, 24
 HKV, G, DH, PAGE, WINDOW = 8, 4, 80, 64, 4096
 DECODE_M = 8      # a decode batch, sublane-padded
+NEMO_MODEL, NEMO_FF, NEMO_VOCAB = 5120, 14336, 131072   # Mistral-Nemo-12B
 
 
 @pytest.fixture(scope="module")
@@ -82,11 +85,17 @@ def test_lutq_matmul_compiles(one_chip, m, kin, n):
 @pytest.mark.parametrize("m,kin,n", [
     (DECODE_M, D_MODEL, D_FF),    # MLP in, decode
     (DECODE_M, D_FF, D_MODEL),    # MLP out, decode
+    (256, D_MODEL, D_FF),         # MLP in, a prefill chunk
     (8 * 544, D_MODEL, D_FF),     # MLP in, 8 prompts of 544 at once
+    (16, NEMO_MODEL, NEMO_FF),    # Mistral-Nemo MLP in, decode
+    (16, NEMO_FF, NEMO_MODEL),    # Mistral-Nemo MLP out, decode
+    (16, NEMO_MODEL, NEMO_VOCAB),  # Mistral-Nemo head, decode
 ])
 def test_lutq_gemv_packed_compiles(one_chip, m, kin, n):
-    fn = functools.partial(lutq_gemv_packed, bm=min(256, m), bn=256,
-                           bk=512 if kin % 512 == 0 else 256, interpret=False)
+    """At the tile ``lutq_dot`` picks by default for the shape."""
+    t = default_tile("packed4", n, kin)
+    fn = functools.partial(lutq_gemv_packed, bm=min(t.bm, m), bn=t.bn,
+                           bk=t.bk, interpret=False)
     _compile(one_chip, fn, ((m, kin), jnp.bfloat16),
              ((kin // 2, n), jnp.uint8), ((16,), jnp.float32))
 
